@@ -2,7 +2,7 @@
 
 Paper artifact → bench:
   Table I    (APSP vs Voronoi-cell runtime)        → bench_table1
-  Fig. 3     (strong scaling, devices)             → bench_fig3 (subprocess)
+  Fig. 3     (strong scaling, devices)             → bench_fig3 (1/2/4 devices)
   Fig. 4     (|S| sweep, runtime breakdown)        → bench_fig4
   Fig. 5/6   (FIFO vs priority queue, msgs)        → bench_fig56
   Fig. 7     (edge-weight range sensitivity)       → bench_fig7
@@ -15,9 +15,6 @@ Each returns a list of CSV rows: (name, us_per_call, derived).
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -72,47 +69,40 @@ def bench_table1():
 
 
 def bench_fig3():
-    """Strong scaling: distributed pipeline at 1/2/4/8 forced host devices.
+    """Strong scaling: distributed pipeline on meshes over the first 1, 2
+    and 4 devices of this process (counts the process lacks are skipped;
+    on a CPU host, run under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4``).
 
-    Each device count runs in a subprocess (jax fixes the device count at
-    init). Derived column = speedup over 1 device.
+    Everything runs in this one process: a chip belongs to the process
+    that first touched it.  Derived column = speedup over 1 device.
     """
-    prog = r"""
-import sys, time
-import numpy as np, jax
-from repro import compat
-from repro.core.dist_steiner import partition_edges, run_dist_steiner
-from repro.data.graphs import rmat_edges, select_seeds
-ndev = int(sys.argv[1])
-shape = {1:(1,1),2:(1,2),4:(2,2),8:(2,4)}[ndev]
-mesh = compat.make_mesh(shape, ("data","model"))
-src, dst, w, n = rmat_edges(13, 8, max_weight=100, seed=0)
-seeds = select_seeds(n, src, dst, 64, strategy="bfs_level", seed=1)
-part = partition_edges(src, dst, w, n, n_replica=shape[0], n_blocks=shape[1])
-r = run_dist_steiner(mesh, part, seeds)  # warm (compile)
-t0 = time.perf_counter()
-r = run_dist_steiner(mesh, part, seeds)
-print(f"RESULT {time.perf_counter()-t0:.4f} {r.total_distance} {r.iterations}")
-"""
+    import jax
+
+    from repro.core.dist_steiner import partition_edges, run_dist_steiner
+    from repro.launch.mesh import make_mesh
+
+    src, dst, w, n = _graph(scale=13)
+    seeds = _seeds(n, src, dst, 64, seed=1)
+    devices = jax.devices()
     rows = []
     base = None
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for ndev in (1, 2, 4, 8):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-        env["PYTHONPATH"] = os.path.join(here, "src")
-        out = subprocess.run(
-            [sys.executable, "-c", prog, str(ndev)],
-            env=env, capture_output=True, text=True, timeout=1200,
+    for ndev, shape in ((1, (1, 1)), (2, (1, 2)), (4, (2, 2))):
+        if ndev > len(devices):
+            break
+        mesh = make_mesh(shape, ("data", "model"), devices=devices[:ndev])
+        part = partition_edges(
+            src, dst, w, n, n_replica=shape[0], n_blocks=shape[1]
         )
-        line = [l for l in out.stdout.splitlines() if l.startswith("RESULT")]
-        assert line, out.stderr[-2000:]
-        dt, dist, iters = line[0].split()[1:]
-        us = float(dt) * 1e6
+        run_dist_steiner(mesh, part, seeds)  # warm (compile)
+        t0 = time.perf_counter()
+        r = run_dist_steiner(mesh, part, seeds)
+        us = (time.perf_counter() - t0) * 1e6
         base = base or us
         rows.append(
             (f"fig3/ndev{ndev}", us,
-             f"speedup={base / us:.2f}x D={dist} iters={iters}")
+             f"speedup={base / us:.2f}x D={r.total_distance} "
+             f"iters={r.iterations}")
         )
     return rows
 

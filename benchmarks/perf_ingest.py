@@ -310,6 +310,9 @@ def run_delta(args) -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--bench", choices=("ingest", "delta"), default="ingest")
     ap.add_argument("--scales", default="12,14,16,18")

@@ -47,6 +47,9 @@ def zipf_stream(rng, pool_size, num_queries, s):
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=9, help="RMAT n = 2^scale")
     ap.add_argument("--edge-factor", type=int, default=8)

@@ -242,7 +242,6 @@ def run_variant(cell: str, name: str, **cfg_kw) -> dict:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
     from repro.configs import get_arch
     from repro.configs.steiner import solver_preset
     from repro.core.dist_steiner import DistSteinerConfig, make_dist_steiner
@@ -267,7 +266,7 @@ def run_variant(cell: str, name: str, **cfg_kw) -> dict:
     )
     base.update(cfg_kw)
     cfg = DistSteinerConfig(n=n, nb=nb, num_seeds=S, **base)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if partition_2d:
             nf = -(-(-(-n // (n_rep * n_blocks))) // 8) * 8
             fn = make_dist_steiner_2d(
@@ -328,6 +327,9 @@ def run_roofline_bench(args) -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--bench", default="handle", choices=("handle", "roofline"))
     # handle bench

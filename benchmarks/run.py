@@ -1,8 +1,8 @@
 """Benchmark harness — one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV. CPU-sized instances; the
-full-scale numbers live in the dry-run/roofline results
-(benchmarks/results/dryrun/ + EXPERIMENTS.md).
+Prints ``name,us_per_call,derived`` CSV for CPU-sized instances.  Every
+bench runs in this one process (a chip belongs to the first process that
+touches it); fig3 uses however many of 1, 2, 4 devices the process has.
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--only table1,fig4,...]
 """
@@ -15,6 +15,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="", help="comma list: table1,fig3,...")
     args = ap.parse_args()

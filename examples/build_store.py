@@ -22,6 +22,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import from_edges
 from repro.data.graphs import rmat_edges
 from repro.graphstore import RmatEdgeSource, build_store, open_store
@@ -30,6 +31,7 @@ from repro.solver import SolverConfig, SteinerSolver
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=14, help="RMAT n = 2^scale")
     ap.add_argument("--edge-factor", type=int, default=8)

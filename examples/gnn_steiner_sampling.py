@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.configs.base import ShapeSpec
 from repro.core import from_edges, steiner_tree
@@ -38,6 +39,7 @@ def steiner_subgraph(g, src, dst, seeds, n):
 
 
 def main() -> None:
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     src, dst, w, n = rmat_edges(11, 8, max_weight=50, seed=3)
     g = from_edges(src, dst, w, n, pad_to=64)

@@ -8,6 +8,7 @@ paper's BFS-level strategy, solves through the unified solver API
 the result against the sequential Mehlhorn oracle.
 """
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import ref, tree_edge_list
 from repro.core.graph import from_edges
 from repro.data.graphs import rmat_edges, select_seeds
@@ -15,6 +16,7 @@ from repro.solver import SolverConfig, SteinerSolver
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1) a weighted scale-free graph (paper Table III family)
     src, dst, w, n = rmat_edges(12, 8, max_weight=100, seed=42)
     print(f"graph: {n} vertices, {2 * len(src)} directed edges")

@@ -11,12 +11,14 @@ execution, LRU result caching.
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import from_edges
 from repro.data.graphs import rmat_edges
 from repro.serve import ServeConfig, SteinerServer
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1) one resident graph, shared by every query
     src, dst, w, n = rmat_edges(10, 8, max_weight=100, seed=42)
     g = from_edges(src, dst, w, n, pad_to=64)

@@ -20,6 +20,9 @@ import time
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
 
     ndev = len(jax.devices())

@@ -11,6 +11,7 @@ latest checkpoint and converge to the same trajectory.
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import LMConfig
 from repro.launch.train import TrainConfig, train
 
@@ -29,6 +30,7 @@ PRESETS = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--preset", default="tiny", choices=sorted(PRESETS))
     ap.add_argument("--steps", type=int, default=200)

@@ -15,7 +15,7 @@ Three passes over the parsed project:
    :func:`repro.knobs.solver_jit`, or passed as a function argument to a
    tracing entry point (``jax.jit(f)``, ``lax.while_loop(cond, body, …)``,
    ``lax.scan`` / ``fori_loop`` / ``cond`` / ``switch``, ``jax.vmap``,
-   ``compat.shard_map``, ``pl.pallas_call``, ``jax.checkpoint``).  Roots
+   ``jax.shard_map``, ``pl.pallas_call``, ``jax.checkpoint``).  Roots
    carry their declared ``static_argnames`` (derived from the knob
    declaration for ``solver_jit``).
 3. **Closure + staticness fixpoint** — tracedness propagates through the

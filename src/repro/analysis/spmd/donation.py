@@ -2,7 +2,7 @@
 
 ``jax.jit(..., donate_argnums=...)`` lets XLA reuse an argument's buffer
 for an output — after the call, the Python-side array is invalid.  Inside
-a traced program a donating jit shows up as a ``pjit`` equation whose
+a traced program a donating jit shows up as a ``jit`` equation whose
 ``donated_invars`` tuple marks which operands were given away.  Reading
 such a variable in any *later* equation of the same scope (or returning
 it) is the EllPatcher bug class from PR 7: the read observes whatever the
@@ -15,7 +15,7 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.spmd.jaxpr_tools import Violation, sub_jaxprs
 
@@ -44,7 +44,7 @@ def _scope(jaxpr: jax_core.Jaxpr, out: List[Violation]) -> None:
                     )
                 )
         donation = eqn.params.get("donated_invars")
-        if eqn.primitive.name == "pjit" and donation and any(donation):
+        if eqn.primitive.name == "jit" and donation and any(donation):
             for v, gone in zip(eqn.invars, donation):
                 if gone and isinstance(v, jax_core.Var):
                     donated[v] = eqn

@@ -62,7 +62,6 @@ def _combo_config(backend: str, mode: str):
     if backend == "mesh1d" and mode != "frontier":
         kw["local_steps"] = 2  # frontier must exchange top-K every round
     if mode == "pallas":
-        kw["interpret"] = True  # host-tracable everywhere, incl. CI runners
         kw["block_rows"] = 8
     if mode in ("frontier", "pallas"):
         kw["frontier_size"] = 8

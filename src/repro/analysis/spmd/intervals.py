@@ -22,7 +22,8 @@ import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
-from jax import core as jax_core
+from jax.core import DropVar
+from jax.extend import core as jax_core
 
 from repro.analysis.spmd.jaxpr_tools import Violation, sub_jaxprs
 
@@ -87,7 +88,7 @@ class _Env:
         return self._m.get(atom, TOP)
 
     def write(self, var, iv: Interval) -> None:
-        if not isinstance(var, jax_core.DropVar):
+        if not isinstance(var, DropVar):
             self._m[var] = iv
 
 
@@ -158,7 +159,7 @@ class _Intervals:
         if name == "cond":
             self._cond(eqn, env, ins)
             return
-        if name in ("pjit", "closed_call", "core_call", "remat", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr"):
+        if name in ("jit", "closed_call", "core_call", "remat", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr"):
             if self._call(eqn, env, ins):
                 return
         # unknown primitive: sound default is ⊤
@@ -371,7 +372,7 @@ def _walk_nested(jaxpr: jax_core.Jaxpr, interp: _Intervals) -> None:
     narrowing casts inside still get proven."""
     for eqn in jaxpr.eqns:
         handled = eqn.primitive.name in (
-            "while", "scan", "cond", "pjit", "closed_call", "remat",
+            "while", "scan", "cond", "jit", "closed_call", "remat",
         )
         for _, sub, consts in sub_jaxprs(eqn):
             if not handled:

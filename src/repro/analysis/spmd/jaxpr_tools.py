@@ -14,7 +14,7 @@ import dataclasses
 import os
 from typing import Dict, Iterator, List, Optional, Tuple
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Finding, norm_path
 from repro.analysis.suppress import suppresses
@@ -126,7 +126,7 @@ def provenance(eqn) -> Provenance:
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
     except Exception:
         frame = None
     if frame is None:

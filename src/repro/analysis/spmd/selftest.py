@@ -27,9 +27,9 @@ from typing import List
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.analysis.findings import Finding
 from repro.analysis.spmd.harness import analyze_jaxpr
+from repro.launch.mesh import make_mesh
 
 SEEDABLE_RULES = ("SP01", "SP02", "SP03", "NU01", "NU02", "DN01")
 
@@ -37,7 +37,7 @@ SEEDABLE_RULES = ("SP01", "SP02", "SP03", "NU01", "NU02", "DN01")
 def _mesh_1d():
     from jax.sharding import PartitionSpec as P
 
-    return compat.make_mesh((1,), ("data",)), P
+    return make_mesh((1,), ("data",)), P
 
 
 def _trace(fn, *args):
@@ -50,7 +50,7 @@ def _seed_sp01():
     def body(x):
         return jnp.sum(x)  # per-rank partial — never psum'd
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
         check_vma=False,
     )
@@ -63,7 +63,7 @@ def _seed_sp02():
     def body(x):
         return jax.lax.psum(jnp.sum(x), "data")
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
         check_vma=False,
     )
@@ -101,7 +101,7 @@ def _seed_sp03():
             jnp.sum(x),
         )
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
         check_vma=False,
     )

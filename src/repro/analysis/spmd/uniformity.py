@@ -29,7 +29,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Sequence
 
-from jax import core as jax_core
+from jax.core import DropVar
+from jax.extend import core as jax_core
 
 from repro.analysis.spmd.jaxpr_tools import (
     REDUCING_COLLECTIVES,
@@ -54,7 +55,7 @@ class _Env:
         return self._m.get(atom, _EMPTY)
 
     def write(self, var, axes: Axes) -> None:
-        if not isinstance(var, jax_core.DropVar):
+        if not isinstance(var, DropVar):
             self._m[var] = axes
 
 
@@ -66,10 +67,12 @@ def _mesh_axis_names(mesh) -> tuple:
     return tuple(shape)
 
 
-def _names_spec_axes(names_entry) -> Axes:
-    """Axes mentioned by one in_names/out_names dict entry."""
+def _spec_axes(spec) -> Axes:
+    """Mesh axes named by one in_specs/out_specs PartitionSpec."""
     out = set()
-    for axes in dict(names_entry or {}).values():
+    for axes in spec or ():
+        if axes is None:
+            continue
         if isinstance(axes, str):
             out.add(axes)
         else:
@@ -85,16 +88,16 @@ def check_shard_map(eqn, out: List[Violation]) -> List[Axes]:
         consts, jaxpr = list(jaxpr.consts), jaxpr.jaxpr
     else:
         consts = []
-    in_names = eqn.params.get("in_names", ())
-    out_names = eqn.params.get("out_names", ())
-    seed = [_names_spec_axes(n) & mesh_axes for n in in_names]
+    in_specs = eqn.params.get("in_specs", ())
+    out_specs = eqn.params.get("out_specs", ())
+    seed = [_spec_axes(spec) & mesh_axes for spec in in_specs]
     # pad for closed-over consts (replicated) if arity differs
     while len(seed) < len(jaxpr.invars):
         seed.insert(0, _EMPTY)
     analyzer = _Uniformity(mesh_axes, out)
     out_varying = analyzer.run(jaxpr, seed[: len(jaxpr.invars)], consts)
-    for i, (ovar_axes, names_entry) in enumerate(zip(out_varying, out_names)):
-        declared = _names_spec_axes(names_entry)
+    for i, (ovar_axes, spec) in enumerate(zip(out_varying, out_specs)):
+        declared = _spec_axes(spec)
         leaked = (ovar_axes - declared) & mesh_axes
         if leaked:
             producer = _producer_of(jaxpr, i) or eqn
@@ -206,7 +209,7 @@ class _Uniformity:
         )
 
     def _generic_higher_order(self, eqn, env: _Env, ins) -> bool:
-        """pjit / closed_call / remat / custom_* — one body, args map 1:1.
+        """jit / closed_call / remat / custom_* — one body, args map 1:1.
 
         Returns False (caller falls back to the union join) when the
         sub-jaxpr arity doesn't line up (e.g. pallas_call, whose invars

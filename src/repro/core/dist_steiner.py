@@ -42,9 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 from repro.core.distance_graph import local_pair_tables
+from repro.core.graph import stable_argsort
 from repro.core.mst import boruvka_dense, prim_dense
 from repro.core.tree import bridge_endpoints
 from repro.core.voronoi import _hist_write
@@ -102,7 +101,7 @@ def partition_edges(
     nb = -(-n // n_blocks)
     nb = -(-nb // block_multiple) * block_multiple
     blk = dst // nb
-    order = np.argsort(blk, kind="stable")
+    order = stable_argsort(blk)
     src, dst, w, blk = src[order], dst[order], w[order], blk[order]
     counts = np.bincount(blk, minlength=n_blocks)
     # round-robin replica assignment within each block
@@ -124,7 +123,7 @@ def partition_edges(
     # stable fill
     pos = np.zeros((n_replica, n_blocks), np.int64)
     bucket_key = rep * n_blocks + blk
-    korder = np.argsort(bucket_key, kind="stable")
+    korder = stable_argsort(bucket_key)
     ks, kd, kw, kk = src[korder], dst[korder], w[korder], bucket_key[korder]
     uniq, starts = np.unique(kk, return_index=True)
     ends = np.r_[starts[1:], len(kk)]
@@ -238,7 +237,7 @@ def partition_ell(
     nb = -(-nb // block_multiple) * block_multiple
     blk = row2v // nb
     # within-block rank in global row order → round-robin replica
-    order = np.argsort(blk, kind="stable")
+    order = stable_argsort(blk)
     bs = blk[order]
     run_start = np.r_[0, np.flatnonzero(bs[1:] != bs[:-1]) + 1]
     run_len = np.diff(np.r_[run_start, bs.shape[0]])
@@ -249,7 +248,7 @@ def partition_ell(
     np.add.at(counts, (rep, blk), 1)
     onbr, owgt, orow, _ = ell_bucket_arrays(counts, k, nb, block_multiple)
     bucket_key = rep * n_blocks + blk
-    korder = np.argsort(bucket_key, kind="stable")  # ascending row order
+    korder = stable_argsort(bucket_key)  # ascending row order
     kk = bucket_key[korder]
     uniq, starts = np.unique(kk, return_index=True)
     ends = np.r_[starts[1:], len(kk)]
@@ -864,7 +863,7 @@ def make_dist_steiner(
     edge_spec = P((*replica_axes, vert_axis))
     state_spec = P(vert_axis)
     rep = P()
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(edge_spec, edge_spec, edge_spec, rep),

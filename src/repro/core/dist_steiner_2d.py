@@ -365,9 +365,7 @@ def make_dist_steiner_2d(
     espec = P((row_axis, col_axis))
     st = P((row_axis, col_axis))
     rep = P()
-    from repro import compat
-
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(espec, espec, espec, rep),

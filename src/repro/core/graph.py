@@ -17,8 +17,9 @@ Conventions
 Graphs larger than host RAM live on disk as ``.gstore`` directories
 (:mod:`repro.graphstore`); ``GraphStore.to_graph()`` materializes this
 container from the memmapped CSR, and ``GraphStore.ell(k)`` builds the
-:class:`EllGraph` view chunkwise without the O(E)-Python :func:`to_ell`
-loop below (their outputs are asserted equal in tests/test_graphstore.py).
+:class:`EllGraph` view straight from that CSR through the same
+:func:`ell_from_csr` fill that :func:`to_ell` uses (their outputs are
+asserted equal in tests/test_graphstore.py).
 """
 
 from __future__ import annotations
@@ -142,36 +143,87 @@ class EllGraph:
     n: int = dataclasses.field(metadata=dict(static=True))
 
 
+def stable_argsort(keys: np.ndarray) -> np.ndarray:
+    """``np.argsort(keys, kind="stable")`` for integer keys in [0, 2**32).
+
+    Two LSD radix passes over 16-bit digits (numpy radix-sorts 16-bit
+    types in O(E)), one when every key fits 16 bits: the same permutation
+    as the comparison sort, several times faster at Graph500 edge counts.
+    """
+    keys = np.asarray(keys)
+    order = np.argsort((keys & 0xFFFF).astype(np.uint16), kind="stable")
+    if keys.size and int(keys.max()) >> 16:
+        hi = (keys[order] >> 16).astype(np.uint16)
+        order = order[np.argsort(hi, kind="stable")]
+    return order
+
+
 def to_ell(g: Graph, k: int, *, pad_rows_to: int = 1) -> EllGraph:
-    """Converts COO → split-row ELL with row width ``k`` (host-side)."""
+    """Converts COO → split-row ELL with row width ``k`` (host-side).
+
+    Each vertex's neighbours keep their COO order (a stable sort by
+    source), then fill its rows through :func:`ell_from_csr`.
+    """
     src = np.asarray(g.src)
     dst = np.asarray(g.dst)
     w = np.asarray(g.w)
     real = np.isfinite(w)
     src, dst, w = src[real], dst[real], w[real]
-    order = np.argsort(src, kind="stable")
-    src, dst, w = src[order], dst[order], w[order]
-    counts = np.bincount(src, minlength=g.n)
-    rows_per_v = np.maximum(1, (counts + k - 1) // k)
-    n_rows = int(rows_per_v.sum())
-    padded_rows = ((n_rows + pad_rows_to - 1) // pad_rows_to) * pad_rows_to
+    order = stable_argsort(src)
+    indptr = np.concatenate([[0], np.cumsum(np.bincount(src, minlength=g.n))])
+    return ell_from_csr(
+        indptr, dst[order], w[order], g.n, k, pad_rows_to=pad_rows_to
+    )
+
+
+def ell_from_csr(
+    indptr,
+    indices,
+    weights,
+    n: int,
+    k: int,
+    *,
+    pad_rows_to: int = 1,
+    rows_per_chunk: int = 1 << 16,
+) -> EllGraph:
+    """Chunkwise CSR → split-row ELLPACK fill.
+
+    A vertex of degree d gets ``max(1, ceil(d / k))`` consecutive rows;
+    padding lanes point at vertex 0 with ``+inf`` weight, and padding
+    rows (up to a multiple of ``pad_rows_to``) alias vertex 0.  Accepts
+    memmaps or in-memory arrays; only ``indptr`` is materialized up
+    front, the edge slabs are touched one vertex-chunk at a time.
+    """
+    indptr = np.asarray(indptr)
+    counts = np.diff(indptr).astype(np.int64)
+    rows_per_v = np.maximum(1, -(-counts // k))
+    row_off = np.concatenate([[0], np.cumsum(rows_per_v)])
+    n_rows = int(row_off[-1])
+    padded_rows = -(-n_rows // pad_rows_to) * pad_rows_to
     nbr = np.zeros((padded_rows, k), np.int32)
     wgt = np.full((padded_rows, k), np.inf, np.float32)
     row2v = np.zeros(padded_rows, np.int32)
-    starts = np.concatenate([[0], np.cumsum(counts)])
-    r = 0
-    for v in range(g.n):
-        lo, hi = starts[v], starts[v + 1]
-        for off in range(0, max(1, hi - lo), k):
-            chunk = slice(lo + off, min(lo + off + k, hi))
-            m = chunk.stop - chunk.start
-            nbr[r, :m] = dst[chunk]
-            wgt[r, :m] = w[chunk]
-            row2v[r] = v
-            r += 1
-    row2v[r:] = 0  # padding rows alias vertex 0 with +inf weights
+    row2v[:n_rows] = np.repeat(np.arange(n, dtype=np.int32), rows_per_v)
+    flat_nbr = nbr.reshape(-1)
+    flat_wgt = wgt.reshape(-1)
+    for v0 in range(0, n, rows_per_chunk):
+        v1 = min(v0 + rows_per_chunk, n)
+        e0, e1 = int(indptr[v0]), int(indptr[v1])
+        if e1 == e0:
+            continue
+        c = counts[v0:v1]
+        edge_v = np.repeat(np.arange(v0, v1, dtype=np.int64), c)
+        within = np.arange(e0, e1) - np.repeat(indptr[v0:v1], c)
+        # consecutive split rows of one vertex are contiguous, so the
+        # j-th edge of vertex v lands at flat slot row_off[v]*k + j
+        flat = row_off[edge_v] * k + within
+        flat_nbr[flat] = indices[e0:e1]
+        flat_wgt[flat] = weights[e0:e1]
     return EllGraph(
-        nbr=jnp.asarray(nbr), wgt=jnp.asarray(wgt), row2v=jnp.asarray(row2v), n=g.n
+        nbr=jnp.asarray(nbr),
+        wgt=jnp.asarray(wgt),
+        row2v=jnp.asarray(row2v),
+        n=n,
     )
 
 
@@ -216,9 +268,9 @@ def bump_graph_version(g: Graph) -> int:
 def ell_view_cached(g: Graph, k: int) -> EllGraph:
     """Memoized :func:`to_ell` keyed on ``(graph_token(g), k)``.
 
-    ``to_ell`` is O(E) host Python — far more expensive than the solve it
-    feeds when queries repeat against one resident graph.  The key is the
-    per-Graph version token (process-unique, never reused — unlike
+    ``to_ell`` is an O(E) host sort and fill — far more expensive than
+    the solve it feeds when queries repeat against one resident graph.
+    The key is the per-Graph version token (process-unique, never reused — unlike
     ``id()``, which the allocator recycles), so a new graph can never hit
     a dead graph's entry and a version bump drops stale views.  The memo
     holds only a weak reference to ``g`` (so retiring a graph frees its

@@ -21,7 +21,8 @@ Sources provided here:
   graph is a function of ``(scale, edge_factor, seed, block_edges)``
   only: edges are drawn in fixed logical blocks with per-block RNG
   streams, so regrouping chunks (``chunk_edges``) never changes the
-  graph, and iterating twice yields identical chunks.
+  graph, iterating twice yields identical chunks, and blocks can be
+  drawn on several threads at once.
 * :class:`TsvEdgeSource` — SNAP-style whitespace edge lists
   (``u v [w]``, ``#`` comments), streamed line-window by line-window.
 * :class:`ArraySource` — in-memory arrays, sliced into chunks (the
@@ -31,7 +32,9 @@ Sources provided here:
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 from typing import Callable, Iterator, Optional, Tuple, Union
 
@@ -46,6 +49,10 @@ Chunk = Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]
 # are regrouped because randomness is keyed per block, not per chunk.
 DEFAULT_BLOCK_EDGES = 1 << 16
 DEFAULT_CHUNK_EDGES = 1 << 16
+# RMAT blocks drawn concurrently (numpy's generators and array ops release
+# the GIL); results are consumed in block order, a window at a time, so
+# the stream and its peak memory do not depend on the thread count
+_RMAT_THREADS = min(8, os.cpu_count() or 1)
 
 
 # ----------------------------------------------------------------------------
@@ -135,8 +142,16 @@ class RmatEdgeSource:
 
     def _blocks(self) -> Iterator[Chunk]:
         perm = self._perm()
-        for i, lo in enumerate(range(0, self.m_target, self.block_edges)):
-            yield self._block(i, lo, min(lo + self.block_edges, self.m_target), perm)
+        spans = [
+            (i, lo, min(lo + self.block_edges, self.m_target))
+            for i, lo in enumerate(range(0, self.m_target, self.block_edges))
+        ]
+        window = 4 * _RMAT_THREADS
+        with ThreadPoolExecutor(_RMAT_THREADS) as pool:
+            for w0 in range(0, len(spans), window):
+                yield from pool.map(
+                    lambda span: self._block(*span, perm), spans[w0 : w0 + window]
+                )
         if self.connect:
             yield from self._path_chunks()
 

@@ -5,9 +5,9 @@ arrays stay on disk until touched.  From it you can get
 
 * ``to_graph()``      — the in-memory padded COO :class:`~repro.core.graph.Graph`
                         the solver consumes (materializes O(M) once);
-* ``ell(k)``          — the ELLPACK view built *chunkwise* from the CSR
-                        (vectorized; never routes through the COO
-                        expansion or the O(n)-Python ``to_ell`` loop);
+* ``ell(k)``          — the ELLPACK view filled *chunkwise* straight
+                        from the CSR (never routes through the COO
+                        expansion and re-sort of ``to_ell``);
 * ``iter_coo(...)``   — bounded-memory chunks of the directed edge list;
 * ``load_partition()``/``load_partition_2d()``/``load_partition_ell()``
   — per-shard loads of a partitioned store, rebuilt into the exact
@@ -260,7 +260,9 @@ class GraphStore:
             indptr, indices, weights = self.indptr, self.indices, self.weights
         else:
             indptr, indices, weights = self.effective_csr()
-        return _ell_from_csr(
+        from repro.core.graph import ell_from_csr
+
+        return ell_from_csr(
             indptr,
             indices,
             weights,
@@ -325,58 +327,6 @@ class _EffectiveSource:
 
     def __iter__(self):
         return self._store.iter_coo()
-
-
-def _ell_from_csr(
-    indptr,
-    indices,
-    weights,
-    n: int,
-    k: int,
-    *,
-    pad_rows_to: int = 1,
-    rows_per_chunk: int = 1 << 16,
-):
-    """Chunkwise CSR → split-row ELLPACK fill (see :meth:`GraphStore.ell`).
-
-    Accepts memmaps or in-memory arrays; only ``indptr`` is materialized
-    up front, the edge slabs are touched one vertex-chunk at a time.
-    """
-    import jax.numpy as jnp
-
-    from repro.core.graph import EllGraph
-
-    indptr = np.asarray(indptr)
-    counts = np.diff(indptr).astype(np.int64)
-    rows_per_v = np.maximum(1, -(-counts // k))
-    row_off = np.concatenate([[0], np.cumsum(rows_per_v)])
-    n_rows = int(row_off[-1])
-    padded_rows = -(-n_rows // pad_rows_to) * pad_rows_to
-    nbr = np.zeros((padded_rows, k), np.int32)
-    wgt = np.full((padded_rows, k), np.inf, np.float32)
-    row2v = np.zeros(padded_rows, np.int32)
-    row2v[:n_rows] = np.repeat(np.arange(n, dtype=np.int32), rows_per_v)
-    flat_nbr = nbr.reshape(-1)
-    flat_wgt = wgt.reshape(-1)
-    for v0 in range(0, n, rows_per_chunk):
-        v1 = min(v0 + rows_per_chunk, n)
-        e0, e1 = int(indptr[v0]), int(indptr[v1])
-        if e1 == e0:
-            continue
-        c = counts[v0:v1]
-        edge_v = np.repeat(np.arange(v0, v1, dtype=np.int64), c)
-        within = np.arange(e0, e1) - np.repeat(indptr[v0:v1], c)
-        # consecutive split rows of one vertex are contiguous, so the
-        # j-th edge of vertex v lands at flat slot row_off[v]*k + j
-        flat = row_off[edge_v] * k + within
-        flat_nbr[flat] = indices[e0:e1]
-        flat_wgt[flat] = weights[e0:e1]
-    return EllGraph(
-        nbr=jnp.asarray(nbr),
-        wgt=jnp.asarray(wgt),
-        row2v=jnp.asarray(row2v),
-        n=n,
-    )
 
 
 def open_store(path: Union[str, Path], *, verify: bool = True) -> GraphStore:

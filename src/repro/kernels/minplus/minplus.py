@@ -8,22 +8,19 @@ The Voronoi-cell hot loop (paper Alg. 4) is, per destination vertex v,
 On MPI this is an asynchronous scatter of messages; on TPU we invert it
 into a *gather + row reduction* over the padded ELL adjacency (rows =
 destination vertices, split at width K — the HavoqGT "vertex delegate"
-analogue, see ``repro.core.graph.to_ell``). No scatter appears anywhere:
-each grid step owns a (BR, K) tile of neighbor ids/weights in VMEM,
-gathers neighbor state, and writes a (BR,) lexicographic minimum.
+analogue, see ``repro.core.graph.to_ell``). No scatter appears anywhere.
 
-Two variants:
+The neighbour gather ``dist[nbr]`` / ``lab[nbr]`` runs in XLA ahead of
+the ``pallas_call``: Mosaic lowers no data-dependent gather from a
+vector, and an XLA gather over HBM has no VMEM bound on N.  The gathered
+tiles are laid out (K, R), rows on lanes, so each grid step reduces four
+(K, BR) tiles — gathered distances, gathered labels, weights, neighbour
+ids — over sublanes and writes lane-dense (1, BR) minima.  (A (BR,)
+output block would have to match XLA's 1024-element tiling of a 1-D
+array, which ties BR to 1024.)
 
-* :func:`minplus_call`         — the distance/label vectors are VMEM
-  residents (constant ``index_map``); right for per-device vertex blocks up
-  to ~1M vertices (2 × 4B × N ≤ ~8MB of VMEM).
-* :func:`minplus_blocked_call` — source-blocked grid ``(rows, src_blocks)``
-  for beyond-VMEM vertex counts: each step gathers only from one (SB,)
-  slice of the distance vector and lex-merges into the output accumulator
-  tile (sequential TPU grid ⇒ safe revisiting).
-
-dtypes: distances/weights f32 or bf16; ids int32. Lexicographic identity:
-(+inf, INT32_MAX, INT32_MAX).
+dtypes: distances/weights f32 or bf16 (upcast to f32 in the kernel); ids
+int32. Lexicographic identity: (+inf, INT32_MAX, INT32_MAX).
 """
 
 from __future__ import annotations
@@ -39,35 +36,23 @@ from repro.kernels import default_interpret
 IMAX = jnp.iinfo(jnp.int32).max
 
 
-def _row_lexmin(cand, lab, src):
-    """Per-row lexicographic argmin of (cand, lab, src) along axis 1."""
-    m = jnp.min(cand, axis=1)
-    e1 = cand == m[:, None]
-    ml = jnp.min(jnp.where(e1, lab, IMAX), axis=1)
-    e2 = e1 & (lab == ml[:, None])
-    ms = jnp.min(jnp.where(e2, src, IMAX), axis=1)
+def _col_lexmin(cand, lab, src):
+    """Per-column lexicographic argmin of (K, BR) (cand, lab, src) tiles,
+    kept (1, BR)."""
+    m = jnp.min(cand, axis=0, keepdims=True)
+    e1 = cand == m
+    ml = jnp.min(jnp.where(e1, lab, IMAX), axis=0, keepdims=True)
+    e2 = e1 & (lab == ml)
+    ms = jnp.min(jnp.where(e2, src, IMAX), axis=0, keepdims=True)
     return m, ml, ms
 
 
-def _lex_merge(m0, l0, s0, m1, l1, s1):
-    """Elementwise lexicographic min of two (dist, lab, src) triples."""
-    take1 = (m1 < m0) | ((m1 == m0) & ((l1 < l0) | ((l1 == l0) & (s1 < s0))))
-    return (
-        jnp.where(take1, m1, m0),
-        jnp.where(take1, l1, l0),
-        jnp.where(take1, s1, s0),
-    )
-
-
-def _kernel(nbr_ref, wgt_ref, dist_ref, lab_ref, out_d, out_l, out_s):
-    nbr = nbr_ref[...]
-    w = wgt_ref[...].astype(jnp.float32)
-    d = jnp.take(dist_ref[...], nbr, axis=0).astype(jnp.float32)
-    lab = jnp.take(lab_ref[...], nbr, axis=0)
-    cand = d + w
-    lab = jnp.where(jnp.isfinite(cand), lab, IMAX)
-    srcm = jnp.where(jnp.isfinite(cand), nbr, IMAX)
-    m, ml, ms = _row_lexmin(cand, lab, srcm)
+def _kernel(dn_ref, ln_ref, wgt_ref, nbr_ref, out_d, out_l, out_s):
+    cand = dn_ref[...].astype(jnp.float32) + wgt_ref[...].astype(jnp.float32)
+    ok = jnp.isfinite(cand)
+    lab = jnp.where(ok, ln_ref[...], IMAX)
+    srcm = jnp.where(ok, nbr_ref[...], IMAX)
+    m, ml, ms = _col_lexmin(cand, lab, srcm)
     out_d[...] = m
     out_l[...] = ml
     out_s[...] = ms
@@ -83,7 +68,7 @@ def minplus_call(
     block_rows: int = 256,
     interpret: bool | None = None,
 ):
-    """VMEM-resident min-plus relaxation.
+    """Min-plus relaxation of (R, K) ELL tiles.
 
     Args:
       nbr: (R, K) int32 neighbor ids (padding → any id with wgt=+inf).
@@ -99,104 +84,20 @@ def minplus_call(
     if interpret is None:
         interpret = default_interpret()
     R, K = nbr.shape
-    N = dist.shape[0]
     assert R % block_rows == 0, (R, block_rows)
-    grid = (R // block_rows,)
-    return pl.pallas_call(
+    tile = pl.BlockSpec((K, block_rows), lambda r: (0, r))
+    row = pl.BlockSpec((1, block_rows), lambda r: (0, r))
+    nbr_t = nbr.T
+    m, ml, ms = pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, K), lambda r: (r, 0)),
-            pl.BlockSpec((block_rows, K), lambda r: (r, 0)),
-            pl.BlockSpec((N,), lambda r: (0,)),  # VMEM resident
-            pl.BlockSpec((N,), lambda r: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows,), lambda r: (r,)),
-            pl.BlockSpec((block_rows,), lambda r: (r,)),
-            pl.BlockSpec((block_rows,), lambda r: (r,)),
-        ],
+        grid=(R // block_rows,),
+        in_specs=[tile, tile, tile, tile],
+        out_specs=[row, row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((R,), jnp.float32),
-            jax.ShapeDtypeStruct((R,), jnp.int32),
-            jax.ShapeDtypeStruct((R,), jnp.int32),
+            jax.ShapeDtypeStruct((1, R), jnp.float32),
+            jax.ShapeDtypeStruct((1, R), jnp.int32),
+            jax.ShapeDtypeStruct((1, R), jnp.int32),
         ],
         interpret=interpret,
-    )(nbr, wgt, dist, lab)
-
-
-def _blocked_kernel(sb, nbr_ref, wgt_ref, dist_ref, lab_ref, out_d, out_l, out_s):
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _init():
-        out_d[...] = jnp.full_like(out_d[...], jnp.inf)
-        out_l[...] = jnp.full_like(out_l[...], IMAX)
-        out_s[...] = jnp.full_like(out_s[...], IMAX)
-
-    nbr = nbr_ref[...]
-    base = s * sb
-    idx = nbr - base
-    inblk = (idx >= 0) & (idx < sb)
-    cidx = jnp.clip(idx, 0, sb - 1)
-    d = jnp.take(dist_ref[...], cidx, axis=0).astype(jnp.float32)
-    lab = jnp.take(lab_ref[...], cidx, axis=0)
-    w = wgt_ref[...].astype(jnp.float32)
-    cand = jnp.where(inblk, d + w, jnp.inf)
-    ok = jnp.isfinite(cand)
-    lab = jnp.where(ok, lab, IMAX)
-    srcm = jnp.where(ok, nbr, IMAX)
-    m, ml, ms = _row_lexmin(cand, lab, srcm)
-    nm, nl, ns = _lex_merge(out_d[...], out_l[...], out_s[...], m, ml, ms)
-    out_d[...] = nm
-    out_l[...] = nl
-    out_s[...] = ns
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_rows", "src_block", "interpret")
-)
-def minplus_blocked_call(
-    nbr: jax.Array,
-    wgt: jax.Array,
-    dist: jax.Array,
-    lab: jax.Array,
-    *,
-    block_rows: int = 256,
-    src_block: int = 1024,
-    interpret: bool | None = None,
-):
-    """Source-blocked variant for beyond-VMEM distance vectors.
-
-    Grid is ``(R/block_rows, N/src_block)``; the output tile is revisited
-    across the second grid dimension and lexicographically accumulated.
-    ``interpret=None`` resolves via :func:`default_interpret`.
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    R, K = nbr.shape
-    N = dist.shape[0]
-    assert R % block_rows == 0 and N % src_block == 0, (R, N)
-    grid = (R // block_rows, N // src_block)
-    kern = functools.partial(_blocked_kernel, src_block)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, K), lambda r, s: (r, 0)),
-            pl.BlockSpec((block_rows, K), lambda r, s: (r, 0)),
-            pl.BlockSpec((src_block,), lambda r, s: (s,)),
-            pl.BlockSpec((src_block,), lambda r, s: (s,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows,), lambda r, s: (r,)),
-            pl.BlockSpec((block_rows,), lambda r, s: (r,)),
-            pl.BlockSpec((block_rows,), lambda r, s: (r,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R,), jnp.float32),
-            jax.ShapeDtypeStruct((R,), jnp.int32),
-            jax.ShapeDtypeStruct((R,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(nbr, wgt, dist, lab)
+    )(dist[nbr_t], lab[nbr_t], wgt.T, nbr_t)
+    return m[0], ml[0], ms[0]

@@ -10,9 +10,9 @@ kernel, so per-round work is O(K·k) like
 VPU row reduction instead of flat segment scatters.
 
 Both drivers are the execution engine behind ``SolverConfig(mode="pallas")``
-(:mod:`repro.solver.backends`).  ``interpret=None`` resolves the Pallas
-execution mode per platform (:func:`default_interpret`): compiled on
-TPU/GPU, interpreter fallback on CPU.
+(:mod:`repro.solver.backends`).  The platform picks the Pallas execution
+mode (:func:`repro.kernels.default_interpret`): compiled on TPU/GPU, the
+interpreter on CPU.
 """
 
 from __future__ import annotations
@@ -31,11 +31,7 @@ from repro.core.voronoi import (
     _round_row,
     init_state,
 )
-from repro.kernels.minplus.minplus import (
-    default_interpret,
-    minplus_blocked_call,
-    minplus_call,
-)
+from repro.kernels.minplus.minplus import minplus_call
 
 IMAX = jnp.iinfo(jnp.int32).max
 INF = jnp.inf
@@ -79,37 +75,12 @@ def _rows_to_vertices(m, ml, ms, row2v, n, st):
     return new, upd
 
 
-def _call_kernel(nbr, wgt, dist, lab, *, block_rows, src_block, interpret):
-    """Dispatch one (rows, k) tile to the resident or source-blocked kernel."""
-    if src_block is None:
-        return minplus_call(
-            nbr, wgt, dist, lab, block_rows=block_rows, interpret=interpret
-        )
-    pad = (-dist.shape[0]) % src_block
-    if pad:
-        dist = jnp.concatenate([dist, jnp.full((pad,), INF)])
-        lab = jnp.concatenate([lab, jnp.full((pad,), IMAX, jnp.int32)])
-    return minplus_blocked_call(
-        nbr,
-        wgt,
-        dist,
-        lab,
-        block_rows=block_rows,
-        src_block=src_block,
-        interpret=interpret,
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_rows", "src_block", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("block_rows",))
 def relax_ell(
     ell: EllGraph,
     st: VoronoiState,
     *,
     block_rows: int = 256,
-    src_block: Optional[int] = None,
-    interpret: Optional[bool] = None,
 ) -> tuple[VoronoiState, jax.Array]:
     """One min-plus relaxation of the full ELL adjacency via the kernel.
 
@@ -118,21 +89,11 @@ def relax_ell(
       (dist, lab, pred) strictly improved (same contract as
       :func:`repro.core.voronoi.relax_dense`).
     """
-    if interpret is None:
-        interpret = default_interpret()
     n = ell.n
     nbr = _pad_rows(ell.nbr, block_rows, 0)
     wgt = _pad_rows(ell.wgt, block_rows, jnp.inf)
     row2v = _pad_rows(ell.row2v, block_rows, 0)
-    m, ml, ms = _call_kernel(
-        nbr,
-        wgt,
-        st.dist,
-        st.lab,
-        block_rows=block_rows,
-        src_block=src_block,
-        interpret=interpret,
-    )
+    m, ml, ms = minplus_call(nbr, wgt, st.dist, st.lab, block_rows=block_rows)
     return _rows_to_vertices(m, ml, ms, row2v, n, st)
 
 
@@ -140,8 +101,6 @@ def relax_ell(
     jax.jit,
     static_argnames=(
         "block_rows",
-        "src_block",
-        "interpret",
         "max_iters",
         "telemetry_rounds",
     ),
@@ -151,8 +110,6 @@ def voronoi_cells_pallas(
     seeds: jax.Array,
     *,
     block_rows: int = 256,
-    src_block: Optional[int] = None,
-    interpret: Optional[bool] = None,
     max_iters: Optional[int] = None,
     telemetry_rounds: int = 0,
 ) -> tuple[VoronoiState, VoronoiStats]:
@@ -175,13 +132,7 @@ def voronoi_cells_pallas(
 
     def body(carry):
         st, it, rlx, msg, _, hist = carry
-        new, upd = relax_ell(
-            ell,
-            st,
-            block_rows=block_rows,
-            src_block=src_block,
-            interpret=interpret,
-        )
+        new, upd = relax_ell(ell, st, block_rows=block_rows)
         ch = jnp.any(upd)
         imp = jnp.sum(upd).astype(jnp.float32)
         dmsg = jnp.sum(jnp.where(upd, deg, 0.0))
@@ -208,8 +159,6 @@ def voronoi_cells_pallas(
     static_argnames=(
         "frontier_size",
         "block_rows",
-        "src_block",
-        "interpret",
         "max_iters",
         "telemetry_rounds",
     ),
@@ -220,8 +169,6 @@ def voronoi_cells_pallas_frontier(
     *,
     frontier_size: int = 1024,
     block_rows: int = 256,
-    src_block: Optional[int] = None,
-    interpret: Optional[bool] = None,
     max_iters: Optional[int] = None,
     telemetry_rounds: int = 0,
 ) -> tuple[VoronoiState, VoronoiStats]:
@@ -246,8 +193,6 @@ def voronoi_cells_pallas_frontier(
     propagate through every split row and the fixpoint equals the dense
     schedule's (asserted against the Dijkstra oracle in tests).
     """
-    if interpret is None:
-        interpret = default_interpret()
     n = ell.n
     R, k = ell.nbr.shape
     K = min(frontier_size, R)  # gathered tiles pad K up to block_rows, not R
@@ -279,15 +224,7 @@ def voronoi_cells_pallas_frontier(
             jnp.where(sel[:, None], ell.wgt[rows], INF), block_rows, INF
         )
         v_of = _pad_rows(ell.row2v[rows], block_rows, 0)
-        m, ml, ms = _call_kernel(
-            tnbr,
-            twgt,
-            st.dist,
-            st.lab,
-            block_rows=block_rows,
-            src_block=src_block,
-            interpret=interpret,
-        )
+        m, ml, ms = minplus_call(tnbr, twgt, st.dist, st.lab, block_rows=block_rows)
         new, upd = _rows_to_vertices(m, ml, ms, v_of, n, st)
         # --- expansion: mark the listed neighbors' rows for re-pull at the
         # expander's (post-update) distance

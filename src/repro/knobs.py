@@ -48,8 +48,6 @@ STATIC_KNOBS = frozenset(
         "ell_pad_rows",
         "frontier_size",
         "block_rows",
-        "src_block",
-        "interpret",
         "pallas_frontier",
         "batch_size",
         "mesh_shape",

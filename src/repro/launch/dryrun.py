@@ -29,7 +29,6 @@ import numpy as np
 
 from repro.configs import ALL_IDS, get_arch
 from repro.configs.base import ShapeSpec
-from repro import compat
 from repro.launch.mesh import make_production_mesh
 from repro.launch import roofline as rl
 from repro.models import gnn as gnn_mod
@@ -148,7 +147,7 @@ def _lm_lower(cfg, shape: ShapeSpec, mesh, dp_axes, kv_chunk: int,
 
 
 def _cost_triple(compiled):
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     coll = rl.collective_bytes(compiled.as_text())
     return (
         float(ca.get("flops", 0.0)),
@@ -397,7 +396,7 @@ def run_cell(arch_id: str, shape: ShapeSpec, multi_pod: bool, out_dir: Path,
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
         n_chips = 512 if multi_pod else 256
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered, mf, cost, analytic = build_cell(arch_id, shape, mesh, multi_pod)
             t_lower = time.time() - t0
             compiled = lowered.compile()

@@ -117,9 +117,7 @@ class Roofline:
 def analyze(compiled, model_flops_total: Optional[float] = None,
             n_chips: int = 256) -> Roofline:
     """Builds the three-term roofline from a compiled executable."""
-    from repro import compat
-
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))
     bts = float(ca.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

@@ -115,8 +115,6 @@ def _pallas_voronoi(ell, seeds, cfg_kw):
             seeds,
             frontier_size=cfg_kw["frontier_size"],
             block_rows=cfg_kw["block_rows"],
-            src_block=cfg_kw["src_block"],
-            interpret=cfg_kw["interpret"],
             max_iters=cfg_kw["max_iters"],
             telemetry_rounds=cfg_kw["telemetry_rounds"],
         )
@@ -124,8 +122,6 @@ def _pallas_voronoi(ell, seeds, cfg_kw):
         ell,
         seeds,
         block_rows=cfg_kw["block_rows"],
-        src_block=cfg_kw["src_block"],
-        interpret=cfg_kw["interpret"],
         max_iters=cfg_kw["max_iters"],
         telemetry_rounds=cfg_kw["telemetry_rounds"],
     )
@@ -140,8 +136,6 @@ def _exec_single_pallas(
     num_seeds,
     mst_algo,
     block_rows,
-    src_block,
-    interpret,
     frontier,
     frontier_size,
     max_iters,
@@ -155,8 +149,6 @@ def _exec_single_pallas(
             frontier=frontier,
             frontier_size=frontier_size,
             block_rows=block_rows,
-            src_block=src_block,
-            interpret=interpret,
             max_iters=max_iters,
             telemetry_rounds=telemetry_rounds,
         ),
@@ -173,8 +165,6 @@ def _exec_batch_pallas(
     num_seeds,
     mst_algo,
     block_rows,
-    src_block,
-    interpret,
     frontier,
     frontier_size,
     max_iters,
@@ -185,8 +175,6 @@ def _exec_batch_pallas(
         frontier=frontier,
         frontier_size=frontier_size,
         block_rows=block_rows,
-        src_block=src_block,
-        interpret=interpret,
         max_iters=max_iters,
         telemetry_rounds=telemetry_rounds,
     )
@@ -199,15 +187,11 @@ def _exec_batch_pallas(
 
 
 def _pallas_static_kw(cfg: SolverConfig) -> dict:
-    """The static kernel knobs of one config, with ``interpret=None``
-    resolved per platform (compiled on TPU/GPU, interpreter on CPU)."""
-    interp = cfg.interpret
-    if interp is None:
-        interp = kops.default_interpret()
+    """The static kernel knobs of one config.  The platform alone picks
+    compiled kernels or the interpreter (:func:`default_interpret`), so
+    a TPU never runs the interpreter on the solver path."""
     return dict(
         block_rows=cfg.block_rows,
-        src_block=cfg.src_block,
-        interpret=interp,
         frontier=cfg.pallas_frontier,
         frontier_size=cfg.frontier_size,
         max_iters=cfg.max_iters,
@@ -282,7 +266,7 @@ class _Backend:
 
         GraphStore inputs materialize the COO once and build the ELL view
         chunkwise straight off the memmaps (skipping both the COO
-        round-trip and the O(E)-Python ``to_ell`` loop); in-memory graphs
+        round-trip and the host re-sort of ``to_ell``); in-memory graphs
         go through the bounded ``ell_view_cached`` memo, so repeated
         ``prepare()`` of one resident graph is free.  The mesh backends
         override this wholesale (partition + device placement).
@@ -486,7 +470,7 @@ class BatchBackend(_Backend):
 
 def _device_mesh(shape, axes):
     """mesh_shape → device mesh, with an eager device-count check."""
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     need = int(np.prod(shape))
     have = len(jax.devices())
@@ -495,7 +479,7 @@ def _device_mesh(shape, axes):
             f"mesh_shape {tuple(shape)} needs {need} devices, "
             f"only {have} available"
         )
-    return compat.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def _place_edges(mesh, arrays, axes):

@@ -65,12 +65,9 @@ class SolverConfig:
       frontier_size: top-K frontier rows per round (mode="frontier", and
         mode="pallas" with ``pallas_frontier=True``); per *device* on
         backend="mesh1d" (each block runs its own priority queue).
-      block_rows: ELL rows per Pallas grid step (mode="pallas").
-      src_block: source-block the distance vector into (SB,) VMEM slices
-        (mode="pallas"); None keeps dist/lab VMEM-resident.
-      interpret: Pallas execution override — None resolves per platform
-        (compiled on TPU/GPU, interpreter on CPU), True forces the
-        interpreter, False forces compiled lowering.
+      block_rows: ELL rows per Pallas grid step (mode="pallas").  The
+        kernel is compiled on TPU/GPU and interpreted on CPU; the
+        platform decides, no knob does.
       pallas_frontier: run the top-K work-compacted kernel schedule
         (O(K·k) per round) instead of full-adjacency kernel rounds
         (mode="pallas" only).
@@ -112,8 +109,6 @@ class SolverConfig:
     frontier_size: int = 1024
     # mode="pallas"
     block_rows: int = 256
-    src_block: Optional[int] = None
-    interpret: Optional[bool] = None
     pallas_frontier: bool = False
     # backend="batch"
     batch_size: int = 8
@@ -174,17 +169,6 @@ class SolverConfig:
                     "telemetry_per_rank requires telemetry_rounds >= 1 "
                     "(the per-rank flight recorder rides the round buffer)"
                 )
-        if self.src_block is not None and not (
-            isinstance(self.src_block, int) and self.src_block >= 1
-        ):
-            raise ValueError(
-                f"src_block must be None or a positive int, got {self.src_block!r}"
-            )
-        if self.interpret is not None and not isinstance(self.interpret, bool):
-            raise ValueError(
-                f"interpret must be None (auto), True, or False, "
-                f"got {self.interpret!r}"
-            )
         if self.pallas_frontier and self.mode != "pallas":
             raise ValueError(
                 f"pallas_frontier=True requires mode='pallas', "

@@ -12,13 +12,13 @@ def main() -> None:
 
     assert len(jax.devices()) == 8, jax.devices()
 
-    from repro import compat
     from repro.core import ref
     from repro.core.dist_steiner import partition_edges, run_dist_steiner
     from repro.data.graphs import er_edges, rmat_edges
+    from repro.launch.mesh import make_mesh
 
-    mesh2 = compat.make_mesh((2, 4), ("data", "model"))
-    mesh3 = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh2 = make_mesh((2, 4), ("data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
     for trial in range(4):
         if trial % 2 == 0:

@@ -29,13 +29,13 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.analysis.spmd.harness import analyze_jaxpr, tiny_graph  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main() -> int:
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     axes = ("data", "model")
 
     def body(x):
@@ -45,7 +45,7 @@ def main() -> int:
         return glob.reshape(1), local.reshape(1)
 
     good = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=(P(axes),),
             out_specs=(P(axes), P(axes)), check_vma=False,
         )
@@ -55,7 +55,7 @@ def main() -> int:
         return jnp.sum(x)  # same partial, but claimed replicated below
 
     bad = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             bad_body, mesh=mesh, in_specs=(P(axes),), out_specs=P(),
             check_vma=False,
         )

@@ -81,7 +81,7 @@ def test_all_combos_clean_modulo_baseline():
 
 
 def test_trace_for_analysis_returns_closed_jaxpr():
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
 
     from repro.analysis.spmd.harness import trace_combo
 
@@ -109,12 +109,11 @@ def test_scoped_suppression_silences_spmd_finding(tmp_path):
         "import jax\n"
         "import jax.numpy as jnp\n"
         "from jax.sharding import PartitionSpec as P\n"
-        "from repro import compat\n"
         "\n"
         "def build(mesh):\n"
         "    def body(x):\n"
         "        return jnp.sum(x)  # jitlint: ignore[SP01]\n"
-        "    return jax.jit(compat.shard_map(\n"
+        "    return jax.jit(jax.shard_map(\n"
         "        body, mesh=mesh, in_specs=(P('data'),), out_specs=P(),\n"
         "        check_vma=False))\n",
         encoding="utf-8",
@@ -127,10 +126,10 @@ def test_scoped_suppression_silences_spmd_finding(tmp_path):
 
     import jax.numpy as jnp
 
-    from repro import compat
     from repro.analysis.spmd.harness import analyze_jaxpr
+    from repro.launch.mesh import make_mesh
 
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jaxpr = m.build(mesh).trace(jnp.arange(8.0)).jaxpr
     assert analyze_jaxpr(jaxpr, context="t") == []
 

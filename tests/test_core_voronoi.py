@@ -55,11 +55,11 @@ def test_voronoi_pallas_matches(trial):
     assert float(stats.messages) > 0
 
 
-@pytest.mark.parametrize("src_block", [None, 32])
+@pytest.mark.parametrize("block_rows", [16, 128])
 @pytest.mark.parametrize("trial", range(3))
-def test_voronoi_pallas_frontier_matches(trial, src_block):
-    """Top-K compacted kernel schedule: same fixpoint as the oracle, for
-    both the VMEM-resident and the source-blocked kernel."""
+def test_voronoi_pallas_frontier_matches(trial, block_rows):
+    """Top-K compacted kernel schedule: same fixpoint as the oracle, with
+    gathered tiles both smaller and larger than the frontier."""
     src, dst, w, n, seeds, edges = random_instance(trial)
     g = from_edges(src, dst, w, n, pad_to=8)
     ell = to_ell(g, k=8, pad_rows_to=64)
@@ -67,8 +67,7 @@ def test_voronoi_pallas_frontier_matches(trial, src_block):
         ell,
         jnp.asarray(seeds),
         frontier_size=32,
-        block_rows=16,
-        src_block=src_block,
+        block_rows=block_rows,
     )
     dist, lab, pred = ref.voronoi_ref(n, edges, seeds.tolist())
     np.testing.assert_allclose(np.asarray(st_.dist), dist)

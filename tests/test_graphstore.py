@@ -83,6 +83,22 @@ def test_rmat_source_invariant_to_chunk_regrouping():
         assert np.array_equal(x, y)
 
 
+def test_rmat_blocks_drawn_on_threads_keep_stream_order():
+    """Blocks drawn concurrently come out in block order: the stream
+    equals drawing every block one after another."""
+    source = RmatEdgeSource(9, 8, seed=5, block_edges=64, connect=False)
+    perm = source._perm()
+    spans = range(0, source.m_target, source.block_edges)
+    want = [source._block(i, lo, lo + source.block_edges, perm)
+            for i, lo in enumerate(spans)]
+    assert len(want) > 32  # more blocks than one window of the pool
+    got = list(source._blocks())
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        for x, y in zip(a, b):
+            assert np.array_equal(x, y)
+
+
 def test_rmat_edges_is_the_chunked_source_concatenated():
     src, dst, w, n = rmat_edges(7, 5, seed=11)
     chunks = list(RmatEdgeSource(7, 5, seed=11))
@@ -144,6 +160,49 @@ def test_ell_from_store_matches_to_ell(tmp_path):
     for f in ("nbr", "wgt", "row2v"):
         assert np.array_equal(np.asarray(getattr(a, f)), np.asarray(getattr(b, f)))
     assert a.n == b.n
+
+
+def _to_ell_loop(g, k, pad_rows_to=1):
+    """The per-vertex loop ``to_ell`` once was: the reference layout."""
+    src, dst, w = (np.asarray(x) for x in (g.src, g.dst, g.w))
+    real = np.isfinite(w)
+    src, dst, w = src[real], dst[real], w[real]
+    order = np.argsort(src, kind="stable")
+    src, dst, w = src[order], dst[order], w[order]
+    counts = np.bincount(src, minlength=g.n)
+    n_rows = int(np.maximum(1, (counts + k - 1) // k).sum())
+    rows = -(-n_rows // pad_rows_to) * pad_rows_to
+    nbr = np.zeros((rows, k), np.int32)
+    wgt = np.full((rows, k), np.inf, np.float32)
+    row2v = np.zeros(rows, np.int32)
+    starts = np.concatenate([[0], np.cumsum(counts)])
+    r = 0
+    for v in range(g.n):
+        lo, hi = starts[v], starts[v + 1]
+        for off in range(0, max(1, hi - lo), k):
+            m = min(lo + off + k, hi) - (lo + off)
+            nbr[r, :m] = dst[lo + off : lo + off + m]
+            wgt[r, :m] = w[lo + off : lo + off + m]
+            row2v[r] = v
+            r += 1
+    return nbr, wgt, row2v
+
+
+@pytest.mark.parametrize("k,pad_rows_to", [(4, 1), (8, 64)])
+def test_to_ell_matches_per_vertex_loop(k, pad_rows_to):
+    src, dst, w, n = rmat_edges(8, 6, seed=4)
+    g = from_edges(src, dst, w, n, pad_to=16)
+    ell = to_ell(g, k, pad_rows_to=pad_rows_to)
+    for got, want in zip((ell.nbr, ell.wgt, ell.row2v), _to_ell_loop(g, k, pad_rows_to)):
+        assert np.array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("high", [1 << 12, 1 << 22])
+def test_stable_argsort_is_numpy_stable_argsort(high):
+    from repro.core.graph import stable_argsort
+
+    keys = np.random.default_rng(high).integers(0, high, 50_000)
+    assert np.array_equal(stable_argsort(keys), np.argsort(keys, kind="stable"))
 
 
 def test_empty_chunks_are_skipped(tmp_path):

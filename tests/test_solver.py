@@ -74,15 +74,15 @@ def test_mode_backend_cross_validation():
 def test_pallas_knobs_validated():
     with pytest.raises(ValueError, match="block_rows"):
         SolverConfig(mode="pallas", block_rows=0)
-    with pytest.raises(ValueError, match="src_block"):
-        SolverConfig(mode="pallas", src_block=0)
-    with pytest.raises(ValueError, match="interpret"):
-        SolverConfig(mode="pallas", interpret="yes")
     with pytest.raises(ValueError, match="pallas_frontier"):
         SolverConfig(mode="bucket", pallas_frontier=True)
+    # the platform, not a knob, picks compiled kernels or the interpreter
+    for gone in ("src_block", "interpret"):
+        with pytest.raises(TypeError, match=gone):
+            SolverConfig(mode="pallas", **{gone: None})
     # valid combinations construct fine
-    SolverConfig(mode="pallas", pallas_frontier=True, src_block=64)
-    SolverConfig(backend="batch", mode="pallas", interpret=True)
+    SolverConfig(mode="pallas", pallas_frontier=True, block_rows=64)
+    SolverConfig(backend="batch", mode="pallas")
 
 
 def test_scalar_knobs_validated():
@@ -417,14 +417,14 @@ def test_mesh_frontier_duplicate_seed_padding_inert():
 def test_mesh_frontier_rejects_legacy_edge_partition():
     """run_dist_steiner's (mesh, Partition) pair has no ELL view."""
     from repro.core.dist_steiner import partition_edges, run_dist_steiner
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     g, n, seeds, edges = _instance(0)
     part = partition_edges(
         np.asarray(g.src), np.asarray(g.dst), np.asarray(g.w), n,
         n_replica=1, n_blocks=1, symmetrize=False,
     )
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(TypeError, match="EllPartition"):
         run_dist_steiner(mesh, part, seeds, mode="frontier")
 

@@ -100,11 +100,11 @@ def test_elastic_restore_changes_sharding(tmp_path):
     """Restore onto an explicit sharding (mesh relayout path)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     tree = {"w": jnp.arange(16, dtype=jnp.float32)}
     save_pytree(tree, tmp_path / "e.npz")
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = {"w": NamedSharding(mesh, P("data"))}
     back = load_pytree(tree, tmp_path / "e.npz", shardings=sh)
     assert back["w"].sharding == sh["w"]
