@@ -46,7 +46,7 @@ from repro.core.distance_graph import local_pair_tables
 from repro.core.graph import stable_argsort
 from repro.core.mst import boruvka_dense, prim_dense
 from repro.core.tree import bridge_endpoints
-from repro.core.voronoi import _hist_write
+from repro.core.voronoi import I32_MAX, _hist_write, hist_init, sat_add
 
 INF = jnp.inf
 IMAX = jnp.iinfo(jnp.int32).max
@@ -345,6 +345,15 @@ def _spec(*names):
     return P(*names)
 
 
+def sat_psum(x: jax.Array, axes) -> jax.Array:
+    """``psum`` of a non-negative int32 count per device, held at 2**31 - 1
+    instead of wrapping: the 16-bit halves are summed apart (exact for
+    fewer than 2**15 devices) and joined only where the total fits."""
+    hi, lo = jax.lax.psum(jnp.stack([x >> 16, x & 0xFFFF]), axes)
+    fits = hi <= (I32_MAX - lo) >> 16
+    return jnp.where(fits, (hi << 16) + lo, I32_MAX)
+
+
 def make_dist_steiner(
     mesh,
     cfg: DistSteinerConfig,
@@ -384,19 +393,20 @@ def make_dist_steiner(
         6 instead of 8 wire bytes per vertex per round.  Both bounds are
         enforced eagerly by :class:`DistSteinerConfig` validation.
         """
-        if cfg.lab_i16:
+        with jax.named_scope("exchange"):
+            if cfg.lab_i16:
+                distf = jax.lax.all_gather(dist_l, vert_axis, tiled=True)
+                lab16 = jax.lax.all_gather(
+                    lab_l.astype(jnp.int16), vert_axis, tiled=True
+                )
+                return distf, lab16.astype(jnp.int32)
+            if cfg.fuse_gather:
+                packed = jnp.stack([dist_l, lab_l.astype(jnp.float32)], axis=0)
+                full = jax.lax.all_gather(packed, vert_axis, axis=1, tiled=True)
+                return full[0], full[1].astype(jnp.int32)
             distf = jax.lax.all_gather(dist_l, vert_axis, tiled=True)
-            lab16 = jax.lax.all_gather(
-                lab_l.astype(jnp.int16), vert_axis, tiled=True
-            )
-            return distf, lab16.astype(jnp.int32)
-        if cfg.fuse_gather:
-            packed = jnp.stack([dist_l, lab_l.astype(jnp.float32)], axis=0)
-            full = jax.lax.all_gather(packed, vert_axis, axis=1, tiled=True)
-            return full[0], full[1].astype(jnp.int32)
-        distf = jax.lax.all_gather(dist_l, vert_axis, tiled=True)
-        labf = jax.lax.all_gather(lab_l, vert_axis, tiled=True)
-        return distf, labf
+            labf = jax.lax.all_gather(lab_l, vert_axis, tiled=True)
+            return distf, labf
 
     def init_block(seeds, off):
         """Paper Alg. 3 INITIALIZATION for my (nb,) block slice.
@@ -436,100 +446,102 @@ def make_dist_steiner(
         pred-walk marking.  ``(esrc, edst, ew)`` is my shard's directed
         edge slice in GLOBAL ids (+inf weights are inert)."""
         # ---- MIN distance edges → G'1 (paper Alg. 5) + Allreduce(MIN)
-        distf, labf = gather_state(dist_l, lab_l)
-        dm_l, um_l, vm_l = local_pair_tables(
-            esrc, edst, ew, distf[esrc], distf[edst], labf[esrc], labf[edst], S
-        )
-        dmat = chunk_pmin(dm_l, INF)
-        um_c = jnp.where(dm_l == dmat, um_l, IMAX)
-        umat = chunk_pmin(um_c, IMAX)
-        vm_c = jnp.where((dm_l == dmat) & (um_l == umat), vm_l, IMAX)
-        vmat = chunk_pmin(vm_c, IMAX)
+        with jax.named_scope("distance_graph"):
+            distf, labf = gather_state(dist_l, lab_l)
+            dm_l, um_l, vm_l = local_pair_tables(
+                esrc, edst, ew, distf[esrc], distf[edst], labf[esrc],
+                labf[edst], S,
+            )
+            dmat = chunk_pmin(dm_l, INF)
+            um_c = jnp.where(dm_l == dmat, um_l, IMAX)
+            umat = chunk_pmin(um_c, IMAX)
+            vm_c = jnp.where((dm_l == dmat) & (um_l == umat), vm_l, IMAX)
+            vmat = chunk_pmin(vm_c, IMAX)
 
         # ---- replicated MST (paper Alg. 3 line 17)
-        wmat = dmat.reshape(S, S)
-        wmat = jnp.minimum(wmat, wmat.T)
-        wmat = jnp.where(jnp.eye(S, dtype=bool), INF, wmat)
-        parent = (
-            prim_dense(wmat) if cfg.mst_algo == "prim" else boruvka_dense(wmat)
-        )
+        with jax.named_scope("mst"):
+            wmat = dmat.reshape(S, S)
+            wmat = jnp.minimum(wmat, wmat.T)
+            wmat = jnp.where(jnp.eye(S, dtype=bool), INF, wmat)
+            parent = (
+                prim_dense(wmat) if cfg.mst_algo == "prim"
+                else boruvka_dense(wmat)
+            )
+        with jax.named_scope("extract"):
+            # ---- bridge pruning + TREE_EDGE (paper Alg. 6), pointer doubling
+            bu, bv, bw, bvalid = bridge_endpoints(dmat, umat, vmat, distf, parent, S)
+            predf = jax.lax.all_gather(pred_l, vert_axis, tiled=True)  # (npad,)
+            ep_tgt_u = jnp.where(bvalid & (bu >= off) & (bu < off + nb), bu - off, nb)
+            ep_tgt_v = jnp.where(bvalid & (bv >= off) & (bv < off + nb), bv - off, nb)
+            marked_l = (
+                jnp.zeros((nb + 1,), jnp.bool_)
+                .at[ep_tgt_u]
+                .set(True)
+                .at[ep_tgt_v]
+                .set(True)[:nb]
+            )
 
-        # ---- bridge pruning + TREE_EDGE (paper Alg. 6), pointer doubling
-        bu, bv, bw, bvalid = bridge_endpoints(dmat, umat, vmat, distf, parent, S)
-        predf = jax.lax.all_gather(pred_l, vert_axis, tiled=True)  # (npad,)
-        ep_tgt_u = jnp.where(bvalid & (bu >= off) & (bu < off + nb), bu - off, nb)
-        ep_tgt_v = jnp.where(bvalid & (bv >= off) & (bv < off + nb), bv - off, nb)
-        marked_l = (
-            jnp.zeros((nb + 1,), jnp.bool_)
-            .at[ep_tgt_u]
-            .set(True)
-            .at[ep_tgt_v]
-            .set(True)[:nb]
-        )
-
-        def mbody(carry):
-            marked_l, ptr, _ = carry
-            markedf = jax.lax.all_gather(marked_l, vert_axis, tiled=True)
-            t = ptr - off
-            inb = (t >= 0) & (t < nb)
-            hit = (
-                jax.ops.segment_max(
-                    jnp.where(inb, markedf.astype(jnp.int32), 0),
-                    jnp.clip(t, 0, nb - 1),
-                    nb,
+            def mbody(carry):
+                marked_l, ptr, _ = carry
+                markedf = jax.lax.all_gather(marked_l, vert_axis, tiled=True)
+                t = ptr - off
+                inb = (t >= 0) & (t < nb)
+                hit = (
+                    jax.ops.segment_max(
+                        jnp.where(inb, markedf.astype(jnp.int32), 0),
+                        jnp.clip(t, 0, nb - 1),
+                        nb,
+                    )
+                    > 0
                 )
-                > 0
+                new = marked_l | hit
+                ch = jax.lax.pmax(
+                    jnp.any(new != marked_l).astype(jnp.int32), all_axes
+                )
+                return new, ptr[ptr], ch > 0
+
+            marked_l, _, _ = jax.lax.while_loop(
+                lambda c: c[2], mbody, (marked_l, predf, jnp.bool_(True))
             )
-            new = marked_l | hit
-            ch = jax.lax.pmax(
-                jnp.any(new != marked_l).astype(jnp.int32), all_axes
+
+            path_edge_l = marked_l & (pred_l != gids)
+            path_w = jnp.where(path_edge_l, dist_l - distf[pred_l], 0.0)
+            total = jax.lax.psum(jnp.sum(path_w), (vert_axis,)) + jnp.sum(bw)
+            nedges = jax.lax.psum(
+                jnp.sum(path_edge_l).astype(jnp.int32), (vert_axis,)
+            ) + jnp.sum(bvalid).astype(jnp.int32)
+
+            stats = jnp.stack([iters, rlx, msg])
+            return (
+                dist_l,
+                lab_l,
+                pred_l,
+                marked_l,
+                path_edge_l,
+                bu,
+                bv,
+                bw,
+                bvalid,
+                total,
+                nedges,
+                stats,
+                hist,
+                histr,
             )
-            return new, ptr[ptr], ch > 0
-
-        marked_l, _, _ = jax.lax.while_loop(
-            lambda c: c[2], mbody, (marked_l, predf, jnp.bool_(True))
-        )
-
-        path_edge_l = marked_l & (pred_l != gids)
-        path_w = jnp.where(path_edge_l, dist_l - distf[pred_l], 0.0)
-        total = jax.lax.psum(jnp.sum(path_w), (vert_axis,)) + jnp.sum(bw)
-        nedges = jax.lax.psum(
-            jnp.sum(path_edge_l).astype(jnp.int32), (vert_axis,)
-        ) + jnp.sum(bvalid).astype(jnp.int32)
-
-        stats = jnp.stack([iters.astype(jnp.float32), rlx, msg])
-        return (
-            dist_l,
-            lab_l,
-            pred_l,
-            marked_l,
-            path_edge_l,
-            bu,
-            bv,
-            bw,
-            bvalid,
-            total,
-            nedges,
-            stats,
-            hist,
-            histr,
-        )
 
     # per-round telemetry row (obs.ROUND_CHANNELS): all channels are
-    # global (psum'd) counts, so the carried history is replica-uniform
-    # and rides a replicated out_spec.  Phantom padding vertices
-    # (gids >= n) never settle; subtract them from the unreached residual.
-    n_ghost = float(npad - cfg.n)
-    hist_init = jnp.zeros((cfg.telemetry_rounds + 1, 4), jnp.float32)
+    # global (psum'd) int32 counts, so the carried history is
+    # replica-uniform and rides a replicated out_spec.  Phantom padding
+    # vertices (gids >= n) never settle; subtract them from the unreached
+    # residual.
+    n_ghost = npad - cfg.n
+    hist0 = hist_init(cfg.telemetry_rounds)
 
     def round_row(front, dmsg, imp, dl):
         unr = (
-            jax.lax.psum(
-                jnp.sum(~jnp.isfinite(dl)).astype(jnp.float32), (vert_axis,)
-            )
-            - n_ghost
+            jax.lax.psum(jnp.sum(~jnp.isfinite(dl)), (vert_axis,)) - n_ghost
         )
-        return jnp.stack([front.astype(jnp.float32), dmsg, imp, unr])
+        return jnp.stack([front, dmsg, imp, unr])
 
     # ---- per-rank flight recorder (cfg.telemetry_per_rank) ----
     # Rank = linear device index in (replica..., vert) axis order, so the
@@ -541,20 +553,12 @@ def make_dist_steiner(
     for _a in replica_axes:
         n_rep_total *= mesh.shape[_a]
     n_ranks = n_rep_total * n_blocks if per_rank else 0
-    histr_init = jnp.zeros(
-        (cfg.telemetry_rounds + 1, n_ranks, 4), jnp.float32
-    )
-
-    def histr_write(histr, it, rows):
-        H = histr.shape[0] - 1
-        return jax.lax.dynamic_update_slice(
-            histr, rows[None], (jnp.minimum(it, H), 0, 0)
-        )
+    histr0 = hist_init(cfg.telemetry_rounds, n_ranks)
 
     def rank_rows(front_l, msg_l, imp_l, unr_l):
         """All-gather this device's channel row → replica-uniform
         (n_ranks, 4).  Callers pre-gate replica-uniform block channels to
-        the replica-0 rank so the per-rank rows sum exactly (integer f32
+        the replica-0 rank so the per-rank rows sum exactly (integer
         counts) to the global channels."""
         row = jnp.stack([front_l, msg_l, imp_l, unr_l])
         return jax.lax.all_gather(row, all_axes, tiled=False)
@@ -611,7 +615,7 @@ def make_dist_steiner(
                 jnp.where(upd, ml, lab_l),
                 jnp.where(upd, ms, pred_l),
             )
-            att = jnp.sum(jnp.isfinite(cand)).astype(jnp.float32)
+            att = jnp.sum(jnp.isfinite(cand))
             return new, upd, att
 
         def merge_replicas(dist_l, lab_l, pred_l):
@@ -628,7 +632,7 @@ def make_dist_steiner(
             # replica-uniform; attribute them to each block's replica-0
             # rank so per-rank rows sum exactly to the global channels.
             is_r0 = sum(jax.lax.axis_index(a) for a in replica_axes) == 0
-            my_ghost = jnp.sum(gids >= cfg.n).astype(jnp.float32)
+            my_ghost = jnp.sum(gids >= cfg.n)
 
         # ---- VORONOI_CELL_ASYNC (paper Alg. 4)
         def vbody(carry):
@@ -638,37 +642,36 @@ def make_dist_steiner(
             def inner(i, c):
                 dl, ll, pl, msg_i = c
                 (dl, ll, pl), _, att = local_relax(dl, ll, pl, distf, labf, theta)
-                return dl, ll, pl, msg_i + att
+                return dl, ll, pl, sat_add(msg_i, att)
 
             dl, ll, pl, msg_i = jax.lax.fori_loop(
-                0, cfg.local_steps, inner, (dist_l, lab_l, pred_l, 0.0)
+                0, cfg.local_steps, inner, (dist_l, lab_l, pred_l, jnp.int32(0))
             )
-            dl, ll, pl = merge_replicas(dl, ll, pl)
+            with jax.named_scope("exchange"):
+                dl, ll, pl = merge_replicas(dl, ll, pl)
             changed_l = (
                 jnp.any(dl != dist_l) | jnp.any(ll != lab_l) | jnp.any(pl != pred_l)
             )
             changed = jax.lax.pmax(changed_l.astype(jnp.int32), all_axes) > 0
-            imp_l = jnp.sum(
-                (dl != dist_l) | (ll != lab_l) | (pl != pred_l)
-            ).astype(jnp.float32)
+            imp_l = jnp.sum((dl != dist_l) | (ll != lab_l) | (pl != pred_l))
             imp = jax.lax.psum(imp_l, (vert_axis,))
-            msg_g = jax.lax.psum(msg_i, all_axes)
+            msg_g = sat_psum(msg_i, all_axes)
             if cfg.mode == "bucket":
                 # frontier = vertices under the bucket threshold this round
-                front_l = jnp.sum(
-                    jnp.isfinite(dl) & (dl <= theta)
-                ).astype(jnp.float32)
+                front_l = jnp.sum(jnp.isfinite(dl) & (dl <= theta))
                 front = jax.lax.psum(front_l, (vert_axis,))
             else:
                 # dense has no explicit frontier; its active set IS the
                 # improved-vertex set
                 front_l = imp_l
                 front = imp
-            hist = _hist_write(hist, it, round_row(front, msg_g, imp, dl))
+            hist = _hist_write(
+                hist, it, round_row(front, msg_g, imp, dl)
+            )
             if per_rank:
-                z = jnp.float32(0.0)
-                unr_l = jnp.sum(~jnp.isfinite(dl)).astype(jnp.float32) - my_ghost
-                histr = histr_write(histr, it, rank_rows(
+                z = jnp.int32(0)
+                unr_l = jnp.sum(~jnp.isfinite(dl)) - my_ghost
+                histr = _hist_write(histr, it, rank_rows(
                     jnp.where(is_r0, front_l, z),
                     msg_i,
                     jnp.where(is_r0, imp_l, z),
@@ -684,32 +687,34 @@ def make_dist_steiner(
             else:
                 work = changed
             return (
-                dl, ll, pl, theta, it + 1, rlx + imp, msg + msg_g, work,
-                hist, histr,
+                dl, ll, pl, theta, it + 1, sat_add(rlx, imp),
+                sat_add(msg, msg_g), work, hist, histr,
             )
 
         def vcond(carry):
             _, _, _, _, it, _, _, work, _, _ = carry
             return work & (it < cap)
 
-        (
-            dist_l, lab_l, pred_l, _, iters, rlx, msg, _, hist, histr
-        ) = jax.lax.while_loop(
-            vcond,
-            vbody,
+        zero = jnp.int32(0)
+        with jax.named_scope("voronoi"):
             (
-                dist_l,
-                lab_l,
-                pred_l,
-                jnp.float32(0.0),
-                jnp.int32(0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.bool_(True),
-                hist_init,
-                histr_init,
-            ),
-        )
+                dist_l, lab_l, pred_l, _, iters, rlx, msg, _, hist, histr
+            ) = jax.lax.while_loop(
+                vcond,
+                vbody,
+                (
+                    dist_l,
+                    lab_l,
+                    pred_l,
+                    jnp.float32(0.0),
+                    zero,
+                    zero,
+                    zero,
+                    jnp.bool_(True),
+                    hist0,
+                    histr0,
+                ),
+            )
 
         return finish(
             dist_l, lab_l, pred_l, src, dst, w, off, gids, iters, rlx, msg,
@@ -750,7 +755,7 @@ def make_dist_steiner(
             # here; only the block-state channels (relaxations/unreached)
             # need replica-0 attribution.
             is_r0 = sum(jax.lax.axis_index(a) for a in replica_axes) == 0
-            my_ghost = jnp.sum(gids >= cfg.n).astype(jnp.float32)
+            my_ghost = jnp.sum(gids >= cfg.n)
 
         def vbody(carry):
             dist_l, lab_l, pred_l, dirty, it, rlx, msg, _, hist, histr = carry
@@ -781,11 +786,12 @@ def make_dist_steiner(
             )
             # --- deliver to the owning blocks: lexicographic pmin over
             # replicas AND blocks, then my (nb,) slice of the result
-            m_g = jax.lax.pmin(m, all_axes)
-            ml_g = jax.lax.pmin(jnp.where(m == m_g, ml, IMAX), all_axes)
-            ms_g = jax.lax.pmin(
-                jnp.where((m == m_g) & (ml == ml_g), ms, IMAX), all_axes
-            )
+            with jax.named_scope("exchange"):
+                m_g = jax.lax.pmin(m, all_axes)
+                ml_g = jax.lax.pmin(jnp.where(m == m_g, ml, IMAX), all_axes)
+                ms_g = jax.lax.pmin(
+                    jnp.where((m == m_g) & (ml == ml_g), ms, IMAX), all_axes
+                )
             m_s = jax.lax.dynamic_slice_in_dim(m_g, off, nb)
             ml_s = jax.lax.dynamic_slice_in_dim(ml_g, off, nb)
             ms_s = jax.lax.dynamic_slice_in_dim(ms_g, off, nb)
@@ -800,21 +806,21 @@ def make_dist_steiner(
             # rows of updated vertices become dirty again (their replicas
             # compute the same upd, so every shard of v's rows agrees)
             dirty = dirty | (upd[lrow] & has_edges)
-            imp_l = jnp.sum(upd).astype(jnp.float32)
+            imp_l = jnp.sum(upd)
             imp = jax.lax.psum(imp_l, (vert_axis,))
-            att = jnp.sum(jnp.isfinite(flat_cand)).astype(jnp.float32)
-            msg_g = jax.lax.psum(att, all_axes)
+            att = jnp.sum(jnp.isfinite(flat_cand))
+            msg_g = sat_psum(att, all_axes)
             # frontier = rows actually popped across every per-device queue
-            front_l = jnp.sum(sel_ok).astype(jnp.float32)
+            front_l = jnp.sum(sel_ok)
             front = jax.lax.psum(front_l, all_axes)
-            hist = _hist_write(hist, it, round_row(front, msg_g, imp, dist_l))
+            hist = _hist_write(
+                hist, it,
+                round_row(front, msg_g, imp, dist_l),
+            )
             if per_rank:
-                z = jnp.float32(0.0)
-                unr_l = (
-                    jnp.sum(~jnp.isfinite(dist_l)).astype(jnp.float32)
-                    - my_ghost
-                )
-                histr = histr_write(histr, it, rank_rows(
+                z = jnp.int32(0)
+                unr_l = jnp.sum(~jnp.isfinite(dist_l)) - my_ghost
+                histr = _hist_write(histr, it, rank_rows(
                     front_l,
                     att,
                     jnp.where(is_r0, imp_l, z),
@@ -822,32 +828,34 @@ def make_dist_steiner(
                 ))
             work = jax.lax.pmax(jnp.any(dirty).astype(jnp.int32), all_axes) > 0
             return (
-                dist_l, lab_l, pred_l, dirty, it + 1, rlx + imp, msg + msg_g,
-                work, hist, histr,
+                dist_l, lab_l, pred_l, dirty, it + 1, sat_add(rlx, imp),
+                sat_add(msg, msg_g), work, hist, histr,
             )
 
         def vcond(carry):
             _, _, _, _, it, _, _, work, _, _ = carry
             return work & (it < cap)
 
-        (
-            dist_l, lab_l, pred_l, _, iters, rlx, msg, _, hist, histr
-        ) = jax.lax.while_loop(
-            vcond,
-            vbody,
+        zero = jnp.int32(0)
+        with jax.named_scope("voronoi"):
             (
-                dist_l,
-                lab_l,
-                pred_l,
-                dirty0,
-                jnp.int32(0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.bool_(True),
-                hist_init,
-                histr_init,
-            ),
-        )
+                dist_l, lab_l, pred_l, _, iters, rlx, msg, _, hist, histr
+            ) = jax.lax.while_loop(
+                vcond,
+                vbody,
+                (
+                    dist_l,
+                    lab_l,
+                    pred_l,
+                    dirty0,
+                    zero,
+                    zero,
+                    zero,
+                    jnp.bool_(True),
+                    hist0,
+                    histr0,
+                ),
+            )
         # my shard's directed edges, flattened from the ELL rows (padding
         # lanes carry +inf weight — inert through the pair tables)
         esrc = jnp.broadcast_to(row2v[:, None], nbr.shape).reshape(-1)
@@ -907,14 +915,16 @@ class DistSteinerResult:
     total_distance: float
     num_edges: int
     iterations: int
-    relaxations: float
-    messages: float
+    relaxations: int
+    messages: int
     # (H+1, 4) per-round telemetry (obs.ROUND_CHANNELS rows); None when
     # the pipeline ran with telemetry_rounds=0
     history: Optional[np.ndarray] = None
     # (H+1, n_ranks, 4) per-rank flight-recorder buffer; None unless the
     # pipeline ran with telemetry_per_rank=True
     per_rank: Optional[np.ndarray] = None
+    # edges the relaxation reads per round over all devices (static)
+    scan_per_round: int = 0
 
     def edge_set(self):
         out = set()
@@ -927,7 +937,7 @@ class DistSteinerResult:
         return out
 
 
-def result_from_device(out, n: int) -> DistSteinerResult:
+def result_from_device(out, n: int, scan_per_round: int = 0) -> DistSteinerResult:
     """Converts the raw 14-tuple pipeline output to a host-side result."""
     (
         dist,
@@ -958,10 +968,11 @@ def result_from_device(out, n: int) -> DistSteinerResult:
         total_distance=float(total),
         num_edges=int(ne),
         iterations=int(stats[0]),
-        relaxations=float(stats[1]),
-        messages=float(stats[2]),
+        relaxations=int(stats[1]),
+        messages=int(stats[2]),
         history=hist if hist.shape[0] > 1 else None,
         per_rank=histr if histr.shape[1] > 0 else None,
+        scan_per_round=scan_per_round,
     )
 
 

@@ -29,10 +29,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.dist_steiner import sat_psum
 from repro.core.distance_graph import local_pair_tables
 from repro.core.mst import boruvka_dense, prim_dense
 from repro.core.tree import bridge_endpoints
-from repro.core.voronoi import _hist_write
+from repro.core.voronoi import _hist_write, hist_init, sat_add
 
 INF = jnp.inf
 IMAX = jnp.iinfo(jnp.int32).max
@@ -151,7 +152,7 @@ def make_dist_steiner_2d(
     col_n = R * nf  # vertices per column block
     cap = min(max_iters if max_iters is not None else 4 * n + 64, 2**31 - 2)
     both = (row_axis, col_axis)
-    n_ghost = float(npad - n)  # phantom padding vertices, never reached
+    n_ghost = npad - n  # phantom padding vertices, never reached
 
     def body(src_l, dst_l, w, seeds):
         r_idx = jax.lax.axis_index(row_axis)
@@ -184,21 +185,22 @@ def make_dist_steiner_2d(
         row_pos = c_idx * nf  # slice offset within the gathered row block
         col_pos = r_idx * nf  # slice offset within the column range
 
-        hist_init = jnp.zeros((telemetry_rounds + 1, 4), jnp.float32)
+        hist0 = hist_init(telemetry_rounds)
         # per-rank flight recorder: every channel is genuinely per-device
         # on the 2D mesh (state slices are disjoint), so the rank row is
         # just this device's local counts; rank = r*C + c via the
         # (row, col) all_gather order.  Disabled → zero rank slots.
         n_ranks = R * C if telemetry_per_rank else 0
-        histr_init = jnp.zeros((telemetry_rounds + 1, n_ranks, 4), jnp.float32)
+        histr0 = hist_init(telemetry_rounds, n_ranks)
         if telemetry_per_rank:
-            my_ghost = jnp.sum(gids >= n).astype(jnp.float32)
+            my_ghost = jnp.sum(gids >= n)
 
         def vbody(carry):
             dist_l, lab_l, pred_l, theta, it, rlx, msg, _, hist, histr = carry
             # gather (dist, lab) of MY ROW's vertex range — n/R wire
-            packed = jnp.stack([dist_l, lab_l.astype(jnp.float32)], axis=0)
-            rowst = jax.lax.all_gather(packed, col_axis, axis=1, tiled=True)
+            with jax.named_scope("exchange"):
+                packed = jnp.stack([dist_l, lab_l.astype(jnp.float32)], axis=0)
+                rowst = jax.lax.all_gather(packed, col_axis, axis=1, tiled=True)
             dist_row, lab_row = rowst[0], rowst[1].astype(jnp.int32)
 
             dsrc = dist_row[src_l]
@@ -215,12 +217,15 @@ def make_dist_steiner_2d(
             loc_ms = jax.ops.segment_min(jnp.where(e2, gsrc, IMAX), dst_l, col_n)
             # column-wide lexicographic merge — three n/C pmins (same
             # conditioned-contribution pattern as the Alg. 5 pair merge)
-            m = jax.lax.pmin(loc_m, row_axis)
-            ml = jax.lax.pmin(jnp.where(loc_m == m, loc_ml, IMAX), row_axis)
-            ms = jax.lax.pmin(
-                jnp.where((loc_m == m) & (loc_ml == ml), loc_ms, IMAX),
-                row_axis,
-            )
+            with jax.named_scope("exchange"):
+                m = jax.lax.pmin(loc_m, row_axis)
+                ml = jax.lax.pmin(
+                    jnp.where(loc_m == m, loc_ml, IMAX), row_axis
+                )
+                ms = jax.lax.pmin(
+                    jnp.where((loc_m == m) & (loc_ml == ml), loc_ms, IMAX),
+                    row_axis,
+                )
 
             # my slice of the column result
             m_s = jax.lax.dynamic_slice_in_dim(m, col_pos, nf)
@@ -239,35 +244,23 @@ def make_dist_steiner_2d(
             # state slices are disjoint across the 2D mesh (each device
             # owns one fine block), so a psum over both axes is the
             # global count — the paper's per-round work metrics
-            imp_l = jnp.sum(upd).astype(jnp.float32)
+            imp_l = jnp.sum(upd)
             imp = jax.lax.psum(imp_l, both)
-            att = jnp.sum(jnp.isfinite(cand)).astype(jnp.float32)
-            msg_g = jax.lax.psum(att, both)
+            att = jnp.sum(jnp.isfinite(cand))
+            msg_g = sat_psum(att, both)
             if mode == "bucket":
-                front_l = jnp.sum(
-                    jnp.isfinite(nd) & (nd <= theta)
-                ).astype(jnp.float32)
+                front_l = jnp.sum(jnp.isfinite(nd) & (nd <= theta))
                 front = jax.lax.psum(front_l, both)
             else:
                 front_l = imp_l
                 front = imp
-            unr = (
-                jax.lax.psum(
-                    jnp.sum(~jnp.isfinite(nd)).astype(jnp.float32), both
-                )
-                - n_ghost
-            )
-            hist = _hist_write(
-                hist, it, jnp.stack([front, msg_g, imp, unr])
-            )
+            unr = jax.lax.psum(jnp.sum(~jnp.isfinite(nd)), both) - n_ghost
+            hist = _hist_write(hist, it, jnp.stack([front, msg_g, imp, unr]))
             if telemetry_per_rank:
-                unr_l = jnp.sum(~jnp.isfinite(nd)).astype(jnp.float32) - my_ghost
+                unr_l = jnp.sum(~jnp.isfinite(nd)) - my_ghost
                 row = jnp.stack([front_l, att, imp_l, unr_l])
                 rows = jax.lax.all_gather(row, both, tiled=False)
-                H = histr.shape[0] - 1
-                histr = jax.lax.dynamic_update_slice(
-                    histr, rows[None], (jnp.minimum(it, H), 0, 0)
-                )
+                histr = _hist_write(histr, it, rows)
             if mode == "bucket":
                 mx = jnp.max(jnp.where(jnp.isfinite(nd), nd, -INF))
                 max_fin = jax.lax.pmax(mx, both)
@@ -277,90 +270,98 @@ def make_dist_steiner_2d(
             else:
                 work = changed
             return (
-                nd, nl, npd, theta, it + 1, rlx + imp, msg + msg_g, work,
-                hist, histr,
+                nd, nl, npd, theta, it + 1, sat_add(rlx, imp),
+                sat_add(msg, msg_g), work, hist, histr,
             )
 
         def vcond(carry):
             _, _, _, _, it, _, _, work, _, _ = carry
             return work & (it < cap)
 
-        (
-            dist_l, lab_l, pred_l, _, iters, rlx, msg, _, hist, histr
-        ) = jax.lax.while_loop(
-            vcond,
-            vbody,
+        zero = jnp.int32(0)
+        with jax.named_scope("voronoi"):
             (
-                dist_l,
-                lab_l,
-                pred_l,
-                jnp.float32(0.0),
-                jnp.int32(0),
-                jnp.float32(0.0),
-                jnp.float32(0.0),
-                jnp.bool_(True),
-                hist_init,
-                histr_init,
-            ),
-        )
+                dist_l, lab_l, pred_l, _, iters, rlx, msg, _, hist, histr
+            ) = jax.lax.while_loop(
+                vcond,
+                vbody,
+                (
+                    dist_l,
+                    lab_l,
+                    pred_l,
+                    jnp.float32(0.0),
+                    zero,
+                    zero,
+                    zero,
+                    jnp.bool_(True),
+                    hist0,
+                    histr0,
+                ),
+            )
 
         # ---- stages 2-6: one-time global gathers (cheap phases)
-        packed = jnp.stack([dist_l, lab_l.astype(jnp.float32)], axis=0)
-        fullst = jax.lax.all_gather(packed, both, axis=1, tiled=True)
-        distf, labf = fullst[0], fullst[1].astype(jnp.int32)
-        gsrc = src_l + r_idx * row_n
-        gdst_fine = dst_l // nf
-        gdst = (gdst_fine * C + c_idx) * nf + (dst_l % nf)
-        dm_l, um_l, vm_l = local_pair_tables(
-            gsrc, gdst, w, distf[gsrc], distf[gdst], labf[gsrc], labf[gdst], S
-        )
-        dmat = jax.lax.pmin(dm_l, both)
-        umat = jax.lax.pmin(jnp.where(dm_l == dmat, um_l, IMAX), both)
-        vmat = jax.lax.pmin(
-            jnp.where((dm_l == dmat) & (um_l == umat), vm_l, IMAX), both
-        )
-        wmat = dmat.reshape(S, S)
-        wmat = jnp.minimum(wmat, wmat.T)
-        wmat = jnp.where(jnp.eye(S, dtype=bool), INF, wmat)
-        parent = prim_dense(wmat) if mst_algo == "prim" else boruvka_dense(wmat)
-        bu, bv, bw, bvalid = bridge_endpoints(dmat, umat, vmat, distf, parent, S)
-
-        predf = jax.lax.all_gather(pred_l, both, tiled=True)
-        tu = jnp.where(bvalid & (bu >= off) & (bu < off + nf), bu - off, nf)
-        tv = jnp.where(bvalid & (bv >= off) & (bv < off + nf), bv - off, nf)
-        marked_l = (
-            jnp.zeros((nf + 1,), jnp.bool_).at[tu].set(True).at[tv].set(True)[:nf]
-        )
-
-        def mbody(carry):
-            marked_l, ptr, _ = carry
-            markedf = jax.lax.all_gather(marked_l, both, tiled=True)
-            t = ptr - off
-            inb = (t >= 0) & (t < nf)
-            hit = (
-                jax.ops.segment_max(
-                    jnp.where(inb, markedf.astype(jnp.int32), 0),
-                    jnp.clip(t, 0, nf - 1),
-                    nf,
-                )
-                > 0
+        with jax.named_scope("distance_graph"):
+            packed = jnp.stack([dist_l, lab_l.astype(jnp.float32)], axis=0)
+            fullst = jax.lax.all_gather(packed, both, axis=1, tiled=True)
+            distf, labf = fullst[0], fullst[1].astype(jnp.int32)
+            gsrc = src_l + r_idx * row_n
+            gdst_fine = dst_l // nf
+            gdst = (gdst_fine * C + c_idx) * nf + (dst_l % nf)
+            dm_l, um_l, vm_l = local_pair_tables(
+                gsrc, gdst, w, distf[gsrc], distf[gdst], labf[gsrc],
+                labf[gdst], S,
             )
-            new = marked_l | hit
-            ch = jax.lax.pmax(jnp.any(new != marked_l).astype(jnp.int32), both)
-            return new, ptr[ptr], ch > 0
+            dmat = jax.lax.pmin(dm_l, both)
+            umat = jax.lax.pmin(jnp.where(dm_l == dmat, um_l, IMAX), both)
+            vmat = jax.lax.pmin(
+                jnp.where((dm_l == dmat) & (um_l == umat), vm_l, IMAX), both
+            )
+        with jax.named_scope("mst"):
+            wmat = dmat.reshape(S, S)
+            wmat = jnp.minimum(wmat, wmat.T)
+            wmat = jnp.where(jnp.eye(S, dtype=bool), INF, wmat)
+            parent = (
+                prim_dense(wmat) if mst_algo == "prim" else boruvka_dense(wmat)
+            )
+        with jax.named_scope("extract"):
+            bu, bv, bw, bvalid = bridge_endpoints(dmat, umat, vmat, distf, parent, S)
 
-        marked_l, _, _ = jax.lax.while_loop(
-            lambda cr: cr[2], mbody, (marked_l, predf, jnp.bool_(True))
-        )
-        path_edge_l = marked_l & (pred_l != gids)
-        path_w = jnp.where(path_edge_l, dist_l - distf[pred_l], 0.0)
-        total = jax.lax.psum(jnp.sum(path_w), both) + jnp.sum(bw)
-        nedges = jax.lax.psum(
-            jnp.sum(path_edge_l).astype(jnp.int32), both
-        ) + jnp.sum(bvalid).astype(jnp.int32)
-        stats = jnp.stack([iters.astype(jnp.float32), rlx, msg])
-        return (dist_l, lab_l, pred_l, marked_l, path_edge_l,
-                bu, bv, bw, bvalid, total, nedges, stats, hist, histr)
+            predf = jax.lax.all_gather(pred_l, both, tiled=True)
+            tu = jnp.where(bvalid & (bu >= off) & (bu < off + nf), bu - off, nf)
+            tv = jnp.where(bvalid & (bv >= off) & (bv < off + nf), bv - off, nf)
+            marked_l = (
+                jnp.zeros((nf + 1,), jnp.bool_).at[tu].set(True).at[tv].set(True)[:nf]
+            )
+
+            def mbody(carry):
+                marked_l, ptr, _ = carry
+                markedf = jax.lax.all_gather(marked_l, both, tiled=True)
+                t = ptr - off
+                inb = (t >= 0) & (t < nf)
+                hit = (
+                    jax.ops.segment_max(
+                        jnp.where(inb, markedf.astype(jnp.int32), 0),
+                        jnp.clip(t, 0, nf - 1),
+                        nf,
+                    )
+                    > 0
+                )
+                new = marked_l | hit
+                ch = jax.lax.pmax(jnp.any(new != marked_l).astype(jnp.int32), both)
+                return new, ptr[ptr], ch > 0
+
+            marked_l, _, _ = jax.lax.while_loop(
+                lambda cr: cr[2], mbody, (marked_l, predf, jnp.bool_(True))
+            )
+            path_edge_l = marked_l & (pred_l != gids)
+            path_w = jnp.where(path_edge_l, dist_l - distf[pred_l], 0.0)
+            total = jax.lax.psum(jnp.sum(path_w), both) + jnp.sum(bw)
+            nedges = jax.lax.psum(
+                jnp.sum(path_edge_l).astype(jnp.int32), both
+            ) + jnp.sum(bvalid).astype(jnp.int32)
+            stats = jnp.stack([iters, rlx, msg])
+            return (dist_l, lab_l, pred_l, marked_l, path_edge_l,
+                    bu, bv, bw, bvalid, total, nedges, stats, hist, histr)
 
     espec = P((row_axis, col_axis))
     st = P((row_axis, col_axis))

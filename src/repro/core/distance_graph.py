@@ -73,6 +73,7 @@ def local_pair_tables(
     return dmat, umat, vmat
 
 
+@jax.named_scope("distance_graph")
 def distance_graph(
     g: Graph, st: VoronoiState, S: int
 ) -> tuple[jax.Array, jax.Array, jax.Array]:

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 INF = jnp.inf
 
 
+@jax.named_scope("mst")
 def prim_dense(wmat: jax.Array) -> jax.Array:
     """Prim's MST over a dense (S, S) weight matrix (INF = non-edge).
 
@@ -51,6 +52,7 @@ def prim_dense(wmat: jax.Array) -> jax.Array:
     return parent
 
 
+@jax.named_scope("mst")
 def boruvka_dense(wmat: jax.Array) -> jax.Array:
     """Borůvka's MST over a dense (S, S) matrix — O(log S) parallel rounds.
 

@@ -57,9 +57,10 @@ def finish_pipeline(
     """Stages 2-5 (distance graph → MST → pruning → walk) from converged
     Voronoi state. Pure jnp — vmap/jit-compose freely."""
     dmat, umat, vmat = dgmod.distance_graph(g, st, S)
-    wmat = dmat.reshape(S, S)
-    wmat = jnp.minimum(wmat, wmat.T)  # symmetrize upper-triangular table
-    wmat = jnp.where(jnp.eye(S, dtype=bool), jnp.inf, wmat)
+    with jax.named_scope("mst"):
+        wmat = dmat.reshape(S, S)
+        wmat = jnp.minimum(wmat, wmat.T)  # symmetrize upper-triangular table
+        wmat = jnp.where(jnp.eye(S, dtype=bool), jnp.inf, wmat)
     if mst_algo == "prim":
         parent = mstmod.prim_dense(wmat)
     elif mst_algo == "boruvka":

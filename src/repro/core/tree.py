@@ -100,6 +100,7 @@ def mark_paths(st: VoronoiState, endpoints: jax.Array) -> jax.Array:
     return marked
 
 
+@jax.named_scope("extract")
 def extract_tree(
     n: int,
     st: VoronoiState,

@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core.graph import EllGraph, Graph
 from repro.knobs import solver_jit
+from repro.obs import ROUND_CHANNELS
 
 INF = jnp.inf
 
@@ -56,13 +57,39 @@ class VoronoiStats:
     """Convergence statistics (the paper's Fig. 5/6 message metrics)."""
 
     iterations: jax.Array  # i32 — number of global rounds
-    relaxations: jax.Array  # f32 — # edge relaxations that improved a vertex
-    messages: jax.Array  # f32 — # edge relaxations attempted ("messages")
-    # (H+1, 4) f32 per-round telemetry ring — rows 0..H-1 hold rounds
+    relaxations: jax.Array  # i32 — vertex improvements (one winning edge each)
+    # i32 — "messages": dense, bucket and pallas charge each improved
+    # vertex its out-degree (generated traffic); frontier and
+    # pallas_frontier count the finite candidates of the expanded lanes
+    messages: jax.Array
+    # (H+1, 4) i32 per-round telemetry ring — rows 0..H-1 hold rounds
     # 0..H-1 in obs.ROUND_CHANNELS order (frontier, messages, relaxations,
-    # unreached); row H absorbs writes from rounds >= H.  None when the
-    # loop ran with telemetry_rounds=0 (the default for direct callers).
+    # unreached); row H accumulates rounds >= H (_hist_write).
+    # None when the loop ran with telemetry_rounds=0 (the default for
+    # direct callers).
     history: Optional[jax.Array] = None
+    # static: edges the schedule's kernel reads each round (the whole edge
+    # array, or the rows it expands); × iterations = the solve's scan
+    scan_per_round: int = dataclasses.field(default=0, metadata=dict(static=True))
+
+
+# Telemetry rows: obs.ROUND_CHANNELS order, int32 counts.  On one device a
+# round's count is at most the number of directed edges; sums over rounds
+# (the loop totals, the spill slot) saturate at I32_MAX instead of wrapping.
+CHANNELS = len(ROUND_CHANNELS)
+UNREACHED = ROUND_CHANNELS.index("unreached")
+I32_MAX = 2**31 - 1
+
+
+def sat_add(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a + b`` for non-negative int32 counts, held at 2**31 - 1 instead
+    of wrapping."""
+    return a + jnp.minimum(b, I32_MAX - a)
+
+
+def hist_init(telemetry_rounds: int, *lead: int) -> jax.Array:
+    """The zeroed (H+1, *lead, 4) telemetry ring."""
+    return jnp.zeros((telemetry_rounds + 1, *lead, CHANNELS), jnp.int32)
 
 
 def _round_row(
@@ -72,17 +99,23 @@ def _round_row(
     dist: jax.Array,
 ) -> jax.Array:
     """One telemetry row in obs.ROUND_CHANNELS order."""
-    unreached = jnp.sum(~jnp.isfinite(dist)).astype(jnp.float32)
+    unreached = jnp.sum(~jnp.isfinite(dist))
     return jnp.stack(
-        [frontier.astype(jnp.float32), messages, relaxations, unreached]
+        [jnp.asarray(x).astype(jnp.int32)
+         for x in (frontier, messages, relaxations, unreached)]
     )
 
 
 def _hist_write(hist: jax.Array, it: jax.Array, row: jax.Array) -> jax.Array:
-    """Writes ``row`` at round ``it``, clamped into the spill slot H."""
+    """Writes ``row`` (shape ``hist.shape[1:]``) at round ``it``.  Rounds
+    >= H land in the spill slot H: the counts add up there (saturating),
+    ``unreached`` keeps the latest round's value."""
     H = hist.shape[0] - 1
+    last = jnp.arange(CHANNELS) == UNREACHED
+    spill = jnp.where(last, row, sat_add(hist[H], row))
+    row = jnp.where(it >= H, spill, row)
     return jax.lax.dynamic_update_slice(
-        hist, row[None, :], (jnp.minimum(it, H), 0)
+        hist, row[None], (jnp.minimum(it, H),) + (0,) * row.ndim
     )
 
 
@@ -223,6 +256,7 @@ def voronoi_cells(
 
 
 @solver_jit
+@jax.named_scope("voronoi")
 def _voronoi_cells(
     g: Graph,
     seeds: jax.Array,
@@ -238,37 +272,44 @@ def _voronoi_cells(
     # a warm init has a different pytree structure than None, so the warm
     # path compiles its own executable and the cold path never retraces
     st0 = init_state(n, seeds) if init is None else init
-    hist0 = jnp.zeros((telemetry_rounds + 1, 4), jnp.float32)
+    hist0 = hist_init(telemetry_rounds)
     # out-degree: an improved vertex "sends a message" to every neighbor
     # (the paper's generated-message-traffic metric, Fig. 6)
-    deg = jax.ops.segment_sum(
-        jnp.isfinite(g.w).astype(jnp.float32), g.src, n
-    )
+    deg = jax.ops.segment_sum(jnp.isfinite(g.w).astype(jnp.int32), g.src, n)
+    # both schedules read the whole edge array every round
+    scanned = g.src.shape[0]
+    zero = jnp.int32(0)
 
     if mode == "dense":
 
         def body(carry):
             st, it, rlx, msg, _, hist = carry
             new, upd = relax_dense(g, st)
-            imp = jnp.sum(upd).astype(jnp.float32)
-            dmsg = jnp.sum(jnp.where(upd, deg, 0.0))
+            imp = jnp.sum(upd)
+            dmsg = jnp.sum(jnp.where(upd, deg, 0))
             # dense has no explicit frontier; its active set IS the
             # improved-vertex set
-            hist = _hist_write(hist, it, _round_row(imp, dmsg, imp, new.dist))
-            return (new, it + 1, rlx + imp, msg + dmsg, _changed(st, new), hist)
+            hist = _hist_write(
+                hist, it, _round_row(imp, dmsg, imp, new.dist)
+            )
+            return (
+                new, it + 1, sat_add(rlx, imp), sat_add(msg, dmsg),
+                _changed(st, new), hist,
+            )
 
         def cond(carry):
             _, it, _, _, changed, _ = carry
             return changed & (it < cap)
 
         st, iters, rlx, msg, _, hist = jax.lax.while_loop(
-            cond, body, (st0, jnp.int32(0), 0.0, 0.0, jnp.bool_(True), hist0)
+            cond, body, (st0, zero, zero, zero, jnp.bool_(True), hist0)
         )
         return st, VoronoiStats(
             iterations=iters,
             relaxations=rlx,
             messages=msg,
             history=hist if telemetry_rounds > 0 else None,
+            scan_per_round=scanned,
         )
 
     if mode == "bucket":
@@ -295,14 +336,19 @@ def _voronoi_cells(
             # quiescent round instead of silently burning the round cap.
             max_fin = jnp.max(jnp.where(jnp.isfinite(new.dist), new.dist, -INF))
             done = ~changed & ((theta >= max_fin) | (d <= 0))
-            imp = jnp.sum(upd).astype(jnp.float32)
-            dmsg = jnp.sum(jnp.where(upd, deg, 0.0))
+            imp = jnp.sum(upd)
+            dmsg = jnp.sum(jnp.where(upd, deg, 0))
             # frontier = vertices under the bucket threshold (the paper's
             # eligible-to-send set this round)
             front = jnp.sum(jnp.isfinite(new.dist) & (new.dist <= theta))
-            hist = _hist_write(hist, it, _round_row(front, dmsg, imp, new.dist))
+            hist = _hist_write(
+                hist, it, _round_row(front, dmsg, imp, new.dist)
+            )
             theta = jnp.where(changed, theta, theta + d)
-            return (new, theta, it + 1, rlx + imp, msg + dmsg, ~done, hist)
+            return (
+                new, theta, it + 1, sat_add(rlx, imp), sat_add(msg, dmsg),
+                ~done, hist,
+            )
 
         def cond(carry):
             _, _, it, _, _, work, _ = carry
@@ -314,9 +360,9 @@ def _voronoi_cells(
             (
                 st0,
                 jnp.float32(0.0),
-                jnp.int32(0),
-                0.0,
-                0.0,
+                zero,
+                zero,
+                zero,
                 jnp.bool_(True),
                 hist0,
             ),
@@ -326,6 +372,7 @@ def _voronoi_cells(
             relaxations=rlx,
             messages=msg,
             history=hist if telemetry_rounds > 0 else None,
+            scan_per_round=scanned,
         )
 
     raise ValueError(
@@ -342,6 +389,7 @@ def _voronoi_cells(
 
 
 @solver_jit
+@jax.named_scope("voronoi")
 def voronoi_cells_frontier(
     ell: EllGraph,
     seeds: jax.Array,
@@ -373,7 +421,10 @@ def voronoi_cells_frontier(
     S_sent = jnp.int32(jnp.iinfo(jnp.int32).max)
     cap = jnp.int32(min(max_rounds if max_rounds is not None else 16 * n + 64, 2**31 - 2))
 
-    hist0 = jnp.zeros((telemetry_rounds + 1, 4), jnp.float32)
+    hist0 = hist_init(telemetry_rounds)
+    # each round reads the K selected rows' k lanes
+    scanned = frontier_size * k
+    zero = jnp.int32(0)
     if init is None:
         st0 = init_state(n, seeds)
         dirty0 = jnp.zeros((R,), jnp.bool_).at[:].set(
@@ -437,24 +488,27 @@ def voronoi_cells_frontier(
         )
         # rows of updated vertices become dirty again
         dirty = dirty | upd[ell.row2v]
-        imp = jnp.sum(upd).astype(jnp.float32)
-        dmsg = jnp.sum(jnp.isfinite(flat_cand)).astype(jnp.float32)
+        imp = jnp.sum(upd)
+        dmsg = jnp.sum(jnp.isfinite(flat_cand))
         # frontier = ELL rows actually expanded this round (the top-K pop)
         hist = _hist_write(
             hist, it, _round_row(jnp.sum(sel_ok), dmsg, imp, new.dist)
         )
-        return (new, dirty, it + 1, rlx + imp, msg + dmsg, hist)
+        return (
+            new, dirty, it + 1, sat_add(rlx, imp), sat_add(msg, dmsg), hist
+        )
 
     def cond(carry):
         _, dirty, it, _, _, _ = carry
         return jnp.any(dirty) & (it < cap)
 
     st, _, iters, rlx, msg, hist = jax.lax.while_loop(
-        cond, body, (st0, dirty0, jnp.int32(0), 0.0, 0.0, hist0)
+        cond, body, (st0, dirty0, zero, zero, zero, hist0)
     )
     return st, VoronoiStats(
         iterations=iters,
         relaxations=rlx,
         messages=msg,
         history=hist if telemetry_rounds > 0 else None,
+        scan_per_round=scanned,
     )

@@ -29,7 +29,9 @@ from repro.core.voronoi import (
     VoronoiStats,
     _hist_write,
     _round_row,
+    hist_init,
     init_state,
+    sat_add,
 )
 from repro.kernels.minplus.minplus import minplus_call
 
@@ -105,6 +107,7 @@ def relax_ell(
         "telemetry_rounds",
     ),
 )
+@jax.named_scope("voronoi")
 def voronoi_cells_pallas(
     ell: EllGraph,
     seeds: jax.Array,
@@ -125,32 +128,39 @@ def voronoi_cells_pallas(
     st0 = init_state(n, seeds)
     # out-degree per vertex: ELL rows of one vertex sum their real lanes
     deg = jax.ops.segment_sum(
-        jnp.sum(jnp.isfinite(ell.wgt), axis=1).astype(jnp.float32), ell.row2v, n
+        jnp.sum(jnp.isfinite(ell.wgt), axis=1).astype(jnp.int32), ell.row2v, n
     )
+    # the kernel expands every tile of the row-padded adjacency each round
+    R, k = ell.nbr.shape
+    scanned = (R + (-R) % block_rows) * k
+    zero = jnp.int32(0)
 
-    hist0 = jnp.zeros((telemetry_rounds + 1, 4), jnp.float32)
+    hist0 = hist_init(telemetry_rounds)
 
     def body(carry):
         st, it, rlx, msg, _, hist = carry
         new, upd = relax_ell(ell, st, block_rows=block_rows)
         ch = jnp.any(upd)
-        imp = jnp.sum(upd).astype(jnp.float32)
-        dmsg = jnp.sum(jnp.where(upd, deg, 0.0))
-        hist = _hist_write(hist, it, _round_row(imp, dmsg, imp, new.dist))
-        return (new, it + 1, rlx + imp, msg + dmsg, ch, hist)
+        imp = jnp.sum(upd)
+        dmsg = jnp.sum(jnp.where(upd, deg, 0))
+        hist = _hist_write(
+            hist, it, _round_row(imp, dmsg, imp, new.dist)
+        )
+        return (new, it + 1, sat_add(rlx, imp), sat_add(msg, dmsg), ch, hist)
 
     def cond(carry):
         _, it, _, _, ch, _ = carry
         return ch & (it < cap)
 
     st, iters, rlx, msg, _, hist = jax.lax.while_loop(
-        cond, body, (st0, jnp.int32(0), 0.0, 0.0, jnp.bool_(True), hist0)
+        cond, body, (st0, zero, zero, zero, jnp.bool_(True), hist0)
     )
     return st, VoronoiStats(
         iterations=iters,
         relaxations=rlx,
         messages=msg,
         history=hist if telemetry_rounds > 0 else None,
+        scan_per_round=scanned,
     )
 
 
@@ -163,6 +173,7 @@ def voronoi_cells_pallas(
         "telemetry_rounds",
     ),
 )
+@jax.named_scope("voronoi")
 def voronoi_cells_pallas_frontier(
     ell: EllGraph,
     seeds: jax.Array,
@@ -202,7 +213,10 @@ def voronoi_cells_pallas_frontier(
     exp0 = jnp.isin(ell.row2v, seeds)
     pull0 = jnp.zeros((R,), jnp.bool_)
     prio0 = jnp.full((R,), INF, jnp.float32)
-    hist0 = jnp.zeros((telemetry_rounds + 1, 4), jnp.float32)
+    hist0 = hist_init(telemetry_rounds)
+    # the kernel expands the K gathered tiles, padded up to block_rows
+    scanned = (K + (-K) % block_rows) * k
+    zero = jnp.int32(0)
 
     def body(carry):
         st, pull, prio, exp, it, rlx, msg, hist = carry
@@ -245,24 +259,28 @@ def voronoi_cells_pallas_frontier(
         prio = jnp.minimum(prio, prio_v[ell.row2v])
         # --- every row of an improved vertex needs (re-)expansion
         exp = exp | upd[ell.row2v]
-        imp = jnp.sum(upd).astype(jnp.float32)
-        dmsg = jnp.sum(jnp.isfinite(twgt)).astype(jnp.float32)
+        imp = jnp.sum(upd)
+        dmsg = jnp.sum(jnp.isfinite(twgt))
         # frontier = dirty rows actually popped this round
         hist = _hist_write(
             hist, it, _round_row(jnp.sum(sel), dmsg, imp, new.dist)
         )
-        return new, pull, prio, exp, it + 1, rlx + imp, msg + dmsg, hist
+        return (
+            new, pull, prio, exp, it + 1, sat_add(rlx, imp),
+            sat_add(msg, dmsg), hist,
+        )
 
     def cond(carry):
         _, pull, _, exp, it, _, _, _ = carry
         return (jnp.any(pull) | jnp.any(exp)) & (it < cap)
 
     st, _, _, _, iters, rlx, msg, hist = jax.lax.while_loop(
-        cond, body, (st0, pull0, prio0, exp0, jnp.int32(0), 0.0, 0.0, hist0)
+        cond, body, (st0, pull0, prio0, exp0, zero, zero, zero, hist0)
     )
     return st, VoronoiStats(
         iterations=iters,
         relaxations=rlx,
         messages=msg,
         history=hist if telemetry_rounds > 0 else None,
+        scan_per_round=scanned,
     )

@@ -8,6 +8,12 @@ loop state that is carried *unconditionally* (gated only by the static
 changes compiled executables, trace counts, or trees.  Tests assert
 this bit-for-bit.
 
+While tracing, every live :func:`span` is also a
+``jax.profiler.TraceAnnotation`` of the same name (when jax is already
+imported), so a profiler trace shows the program's spans on the device
+trace's clock; and Python's garbage collections are recorded as ``gc``
+spans (generation, objects collected).
+
 Typical use::
 
     from repro import obs
@@ -17,13 +23,17 @@ Typical use::
     obs.export_chrome_trace("trace.json")     # load in ui.perfetto.dev
     print(obs.prometheus_text())              # scrape-format metrics
 
-The module is import-safe everywhere (stdlib + numpy only — no jax), so
-the graphstore CLI and serve engine instrument themselves without
-touching the accelerator stack.
+The module is import-safe everywhere (stdlib + numpy only — it never
+imports jax itself), so the graphstore CLI and serve engine instrument
+themselves without touching the accelerator stack.
 """
 
 from __future__ import annotations
 
+import contextlib
+import gc
+import sys
+import threading
 import time
 from typing import Dict, Optional
 
@@ -42,6 +52,8 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "Tracer",
+    "add_counter",
+    "add_span",
     "counter",
     "disable",
     "emit_round_telemetry",
@@ -61,7 +73,8 @@ __all__ = [
 ]
 
 # Channel order of every per-round telemetry row, shared by all fixpoint
-# loops (voronoi dense/bucket/frontier, pallas, mesh1d, mesh2d).
+# loops (voronoi dense/bucket/frontier, pallas, mesh1d, mesh2d): int32
+# counts.
 ROUND_CHANNELS = ("frontier", "messages", "relaxations", "unreached")
 
 _registry: Optional[MetricsRegistry] = None
@@ -90,20 +103,66 @@ def enable(trace: bool = True, metrics: bool = True) -> None:
         _registry = MetricsRegistry()
     if trace and _tracer is None:
         _tracer = Tracer()
+    if _tracer is not None and _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 def disable() -> None:
     """Stops recording; accumulated data stays readable via registry()/tracer()."""
     global _enabled
     _enabled = False
+    if _gc_span in gc.callbacks:
+        gc.callbacks.remove(_gc_span)
 
 
 def reset() -> None:
     """Drops all recorded data and returns to the disabled state (tests)."""
-    global _enabled, _registry, _tracer
-    _enabled = False
+    global _registry, _tracer
+    disable()
     _registry = None
     _tracer = None
+
+
+def _annotation(name: str):
+    """jax's profiler annotation ``name`` when jax is already loaded (the
+    span then shows in a profiler trace), else None: obs never imports
+    jax itself."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
+
+
+@contextlib.contextmanager
+def _annotated(ann, cm):
+    with ann, cm as value:
+        yield value
+
+
+_gc_state = threading.local()
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook while tracing: one ``gc`` span per collection."""
+    if phase == "start":
+        _gc_state.ann = _annotation("gc")
+        if _gc_state.ann is not None:
+            _gc_state.ann.__enter__()
+        _gc_state.t0 = time.perf_counter()
+        return
+    t0 = getattr(_gc_state, "t0", None)
+    if t0 is None:
+        return
+    _gc_state.t0 = None
+    t1 = time.perf_counter()
+    if _gc_state.ann is not None:
+        _gc_state.ann.__exit__(None, None, None)
+    if _tracer is not None:
+        _tracer.add_span(
+            "gc", t0, t1, generation=info.get("generation"),
+            collected=info.get("collected"),
+        )
 
 
 def enabled() -> bool:
@@ -130,9 +189,12 @@ def now() -> float:
 
 
 def span(name: str, tid: int = 0, **args):
-    """A live span on the global tracer, or the shared no-op when off."""
+    """A live span on the global tracer (and the profiler's, when jax is
+    loaded), or the shared no-op when off."""
     if _enabled and _tracer is not None:
-        return _tracer.span(name, tid=tid, **args)
+        cm = _tracer.span(name, tid=tid, **args)
+        ann = _annotation(name)
+        return cm if ann is None else _annotated(ann, cm)
     return _NOOP_SPAN
 
 
@@ -140,6 +202,13 @@ def add_span(name: str, t_start: float, t_end: float, tid: int = 0, **args) -> N
     """Retroactive span (no-op when disabled); stamps from time.perf_counter()."""
     if _enabled and _tracer is not None:
         _tracer.add_span(name, t_start, t_end, tid=tid, **args)
+
+
+def add_counter(name: str, t: float, values: Dict[str, float], tid: int = 0) -> None:
+    """A counter sample on the global tracer (no-op when disabled); ints
+    stay exact."""
+    if _enabled and _tracer is not None:
+        _tracer.add_counter(name, t, values, tid=tid)
 
 
 def counter(name: str, help: str = "", labels=None) -> Optional[Counter]:
@@ -180,23 +249,21 @@ def emit_round_telemetry(
     *,
     label: str,
     tid: int = 0,
-    extra_args: Optional[Dict[str, object]] = None,
     per_rank=None,
 ) -> None:
     """Renders per-round convergence telemetry into the trace.
 
     ``per_round`` is the (R, 4) host array of ROUND_CHANNELS rows carried
     out of a fixpoint loop.  The compiled loop has no host-visible clock,
-    so the R round spans evenly subdivide the real ``[t_start, t_end]``
-    solve interval — flagged ``synthetic_timing`` so trace readers don't
-    mistake them for measured durations.  Counter events at each round
-    boundary draw the convergence curves (frontier/messages/relaxations/
-    unreached) as Perfetto tracks.  ``per_rank`` — the (R, n_ranks, 4)
-    flight-recorder buffer, when the solve ran with
-    ``telemetry_per_rank=True`` — additionally renders one
-    ``rank[{label}/{r}]`` counter track per mesh device, making load
-    imbalance visible round by round.  No-op when tracing is off or the
-    solve recorded zero rounds.
+    so the rounds' counter samples (``convergence[{label}]``, one track
+    of the four channels) are placed at even steps across the real
+    ``[t_start, t_end]`` solve interval: their order is real, their
+    spacing is not (a profiler trace's device events give each round's
+    real time).  ``per_rank`` — the (R, n_ranks, 4) flight-recorder
+    buffer, when the solve ran with ``telemetry_per_rank=True`` —
+    additionally renders one ``rank[{label}/{r}]`` counter track per mesh
+    device, making load imbalance visible round by round.  No-op when
+    tracing is off or the solve recorded zero rounds.
     """
     if not tracing() or per_round is None:
         return
@@ -205,27 +272,11 @@ def emit_round_telemetry(
         return
     dt = (t_end - t_start) / rounds
     for r in range(rounds):
-        row = per_round[r]
-        values = {c: float(row[i]) for i, c in enumerate(ROUND_CHANNELS)}
-        args = {"round": r, "synthetic_timing": True, **values}
-        if extra_args:
-            args.update(extra_args)
-        _tracer.add_span(
-            f"round[{label}]",
-            t_start + r * dt,
-            t_start + (r + 1) * dt,
-            tid=tid,
-            **args,
-        )
-        _tracer.add_counter(
-            f"convergence[{label}]", t_start + r * dt, values, tid=tid
-        )
+        values = {c: int(v) for c, v in zip(ROUND_CHANNELS, per_round[r])}
+        _tracer.add_counter(f"convergence[{label}]", t_start + r * dt, values, tid=tid)
     if per_rank is not None:
         for r in range(min(rounds, int(per_rank.shape[0]))):
             t = t_start + r * dt
             for k in range(int(per_rank.shape[1])):
-                vals = {
-                    c: float(per_rank[r, k, i])
-                    for i, c in enumerate(ROUND_CHANNELS)
-                }
+                vals = {c: int(v) for c, v in zip(ROUND_CHANNELS, per_rank[r, k])}
                 _tracer.add_counter(f"rank[{label}/{k}]", t, vals, tid=tid)

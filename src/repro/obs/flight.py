@@ -4,7 +4,7 @@ The paper's §VI evaluation is phrased in *per-rank* measurements —
 message counts, relaxation load, straggler behavior across MPI
 processes.  A mesh solve run with ``SolverConfig.telemetry_per_rank=True``
 carries the same measurements out of the fixpoint loop as a
-``(rounds, n_ranks, 4)`` buffer (``SolveTelemetry.per_rank``, channel
+``(rounds, n_ranks, 4)`` int32 buffer (``SolveTelemetry.per_rank``, channel
 order :data:`repro.obs.ROUND_CHANNELS`); this module turns that buffer
 into the numbers an operator acts on:
 
@@ -16,7 +16,7 @@ into the numbers an operator acts on:
   * **message skew** — the rank-total spread of the messages channel;
   * **ghost-corrected rank totals** that sum exactly to the global
     channels (the engines subtract each block's padding rows in-loop,
-    so consistency is bit-exact for integer-valued f32 counts).
+    so consistency is bit-exact for the integer counts).
 
 Like the rest of :mod:`repro.obs` this file is import-safe without jax
 (numpy + stdlib only) — reports can be rendered on machines with no
@@ -120,13 +120,13 @@ def check_consistency(per_rank, per_round, *, label: str = "") -> None:
     """Asserts the flight recording sums exactly to the global channels.
 
     The engines attribute replica-uniform block channels to one rank and
-    subtract ghost padding per block, so for integer-valued f32 counts
-    the per-round rank sums must equal ``per_round`` bit-for-bit.
+    subtract ghost padding per block, so the per-round rank sums of the
+    integer counts must equal ``per_round`` exactly.
     Raises ValueError with the first divergent round otherwise.
     """
-    arr = np.asarray(per_rank, np.float32)
-    glob = np.asarray(per_round, np.float32)
-    sums = arr.sum(axis=1, dtype=np.float32)
+    arr = np.asarray(per_rank, np.float64)
+    glob = np.asarray(per_round, np.float64)
+    sums = arr.sum(axis=1)
     rr = min(sums.shape[0], glob.shape[0])
     if not np.array_equal(sums[:rr], glob[:rr]):
         bad = int(np.argwhere(~(sums[:rr] == glob[:rr]).all(axis=1))[0][0])
@@ -150,7 +150,7 @@ def analyze(per_rank, *, label: str = "") -> FlightReport:
         imb.sum(axis=0, where=active) / np.maximum(active.sum(axis=0), 1),
         1.0,
     )
-    peak_imb = imb.max(axis=0) if rounds else np.ones(4)
+    peak_imb = imb.max(axis=0) if rounds else np.ones(len(ROUND_CHANNELS))
     msg_tot = rank_totals[:, MSG]
     skew = (
         float(msg_tot.max() / msg_tot.mean()) if msg_tot.mean() > 0 else 1.0
@@ -203,9 +203,9 @@ def load_flight(path: str) -> Dict[str, object]:
         doc = json.load(f)
     if "per_rank" not in doc:
         raise ValueError(f"{path}: not a flight file (no 'per_rank' key)")
-    doc["per_rank"] = np.asarray(doc["per_rank"], np.float32)
+    doc["per_rank"] = np.asarray(doc["per_rank"], np.float64)
     if doc.get("per_round") is not None:
-        doc["per_round"] = np.asarray(doc["per_round"], np.float32)
+        doc["per_round"] = np.asarray(doc["per_round"], np.float64)
     return doc
 
 

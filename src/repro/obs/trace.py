@@ -9,14 +9,12 @@ paper-§VI curves render as tracks under the solve span.
 Two recording styles coexist:
 
   with tracer.span("solve", mode="frontier"): ...   # live timing
-  tracer.add_span("round", t0, t1, round=3, ...)    # retroactive
+  tracer.add_span("serve:queue_wait", t0, t1, ...)  # retroactive
 
-Retroactive spans matter in two places where a context manager cannot
-sit: the serve engine's queue-wait (the span *starts* at submit() but is
-only known to have ended at flush()), and per-round solve telemetry
-(rounds happen inside one compiled ``while_loop``; their host-visible
-timestamps are synthesized after the fact and flagged
-``synthetic_timing`` in the event args).
+Retroactive spans are for what a context manager cannot enclose: the
+serve engine's queue-wait *starts* at submit() but is only known to have
+ended at batch assembly inside flush(), and a garbage collection is
+reported by a start and a stop callback.
 
 Like :mod:`repro.obs.metrics`, this module is stdlib-only — no jax
 import — so the graphstore CLI can trace ingestion on machines where the
@@ -28,6 +26,7 @@ from __future__ import annotations
 import atexit
 import contextlib
 import json
+import numbers
 import os
 import sys
 import threading
@@ -58,7 +57,9 @@ class Tracer:
 
     def __init__(self, process_name: str = "repro") -> None:
         self._events: List[Dict[str, Any]] = []
-        self._lock = threading.Lock()
+        # re-entrant: a garbage collection can start while this thread
+        # holds the lock, and its callback (repro.obs) records a span
+        self._lock = threading.RLock()
         self._t0 = time.perf_counter()
         self._process_name = process_name
         self._open: Dict[object, tuple] = {}
@@ -114,29 +115,19 @@ class Tracer:
     def add_counter(
         self, name: str, t: float, values: Dict[str, float], tid: int = 0
     ) -> None:
-        """Records a counter sample (renders as a track of stacked series)."""
+        """Records a counter sample (renders as a track of stacked series);
+        integer values stay exact ints."""
         ev = {
             "name": name,
             "ph": "C",
             "ts": self._us(t),
             "pid": 0,
             "tid": tid,
-            "args": {k: float(v) for k, v in values.items()},
+            "args": {
+                k: int(v) if isinstance(v, numbers.Integral) else float(v)
+                for k, v in values.items()
+            },
         }
-        with self._lock:
-            self._events.append(ev)
-
-    def add_instant(self, name: str, tid: int = 0, **args) -> None:
-        ev = {
-            "name": name,
-            "ph": "i",
-            "s": "t",
-            "ts": self._us(time.perf_counter()),
-            "pid": 0,
-            "tid": tid,
-        }
-        if args:
-            ev["args"] = args
         with self._lock:
             self._events.append(ev)
 

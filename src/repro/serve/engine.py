@@ -565,6 +565,10 @@ class SteinerServer:
         already completed in this call are held for the next ``flush``,
         and the exception propagates — no ticket is ever dropped.
         """
+        with obs.span("serve:flush"):
+            return self._flush()
+
+    def _flush(self) -> Dict[int, QueryResult]:
         # deliver results stranded by a previously failed flush first
         out: Dict[int, QueryResult] = self._ready
         self._ready = {}
@@ -584,31 +588,23 @@ class SteinerServer:
                 riders: List[
                     Tuple[_Pending, Optional[QueryResult], bool]
                 ] = []
-                t_assemble = time.perf_counter()
-                while queue and len(lanes) < B:
-                    p = queue.popleft()
-                    hit = self.cache.get(p.plan.key)
-                    from_cache = hit is not None
-                    if hit is None:
-                        # invalidated by an epoch bump but state retained:
-                        # re-solve warm (affected cells only) instead of
-                        # burning a cold batch lane
-                        hit = self._warm_resolve(p.plan)
-                    if hit is None and p.plan.key not in lane_of:
-                        lane_of[p.plan.key] = len(lanes)
-                        lanes.append(p.plan.padded)
-                    riders.append((p, hit, from_cache))
+                with obs.span("serve:assemble", bucket=bucket):
+                    while queue and len(lanes) < B:
+                        p = queue.popleft()
+                        hit = self.cache.get(p.plan.key)
+                        from_cache = hit is not None
+                        if hit is None:
+                            # invalidated by an epoch bump but state
+                            # retained: re-solve warm (affected cells
+                            # only) instead of burning a cold batch lane
+                            hit = self._warm_resolve(p.plan)
+                        if hit is None and p.plan.key not in lane_of:
+                            lane_of[p.plan.key] = len(lanes)
+                            lanes.append(p.plan.padded)
+                        riders.append((p, hit, from_cache))
                 t_assembled = time.perf_counter()
                 t_done = t_assembled
                 if obs.tracing():
-                    obs.add_span(
-                        "serve:assemble",
-                        t_assemble,
-                        t_assembled,
-                        bucket=bucket,
-                        lanes=len(lanes),
-                        riders=len(riders),
-                    )
                     # retroactive queue-wait span per ticket in this batch
                     for p, _, _ in riders:
                         obs.add_span(
@@ -676,29 +672,23 @@ class SteinerServer:
                                 key, bucket,
                                 st_dist[i], st_lab[i], st_pred[i],
                             )
-                t_stash = time.perf_counter()
-                for p, hit, from_cache in riders:
-                    if hit is None:
-                        hit = fresh_by_key[p.plan.key]
-                        ready_at = t_done  # waited for the batch execute
-                    else:
-                        # cache hits AND warm re-solves were ready once
-                        # assembly finished
-                        ready_at = t_assembled
-                    if from_cache:
-                        self._m_hits.inc()
-                    self._m_completed.inc()
-                    lat = ready_at - p.t_submit
-                    self._m_lat["cached" if from_cache else "fresh"].observe(lat)
-                    out[p.ticket] = hit.with_latency(lat, from_cache)
-                if obs.tracing():
-                    obs.add_span(
-                        "serve:stash",
-                        t_stash,
-                        time.perf_counter(),
-                        bucket=bucket,
-                        results=len(riders),
-                    )
+                with obs.span(
+                    "serve:stash", bucket=bucket, results=len(riders)
+                ):
+                    for p, hit, from_cache in riders:
+                        if hit is None:
+                            hit = fresh_by_key[p.plan.key]
+                            ready_at = t_done  # waited for the batch execute
+                        else:
+                            # cache hits AND warm re-solves were ready once
+                            # assembly finished
+                            ready_at = t_assembled
+                        if from_cache:
+                            self._m_hits.inc()
+                        self._m_completed.inc()
+                        lat = ready_at - p.t_submit
+                        self._m_lat["cached" if from_cache else "fresh"].observe(lat)
+                        out[p.ticket] = hit.with_latency(lat, from_cache)
                 self._t_last = t_done
         self._g_queue_depth.set(float(self.pending()))
         return out

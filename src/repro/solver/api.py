@@ -193,12 +193,21 @@ class PreparedGraph:
             )
             if ctr is not None:
                 ctr.inc(out.telemetry.messages)
+            label = f"{self.backend}/{cfg.mode}"
             obs.emit_round_telemetry(
                 out.telemetry.per_round,
                 t0,
                 t1,
-                label=f"{self.backend}/{cfg.mode}",
+                label=label,
                 per_rank=out.telemetry.per_rank,
+            )
+            # the solve's exact totals, one sample per solve
+            t = out.telemetry
+            totals = {"iterations": t.iterations, "messages": t.messages,
+                      "relaxations": t.relaxations, "scanned": t.scanned}
+            obs.add_counter(
+                f"solve_totals[{label}]", t1,
+                {k: v for k, v in totals.items() if v is not None},
             )
         return out
 

@@ -36,7 +36,6 @@ from repro.solver.config import BACKEND_MODES, SolverConfig
 from repro.knobs import solver_jit
 from repro.solver.registry import (
     SolveOutput,
-    SolveTelemetry,
     register_backend,
     telemetry_from_counts,
 )
@@ -297,24 +296,28 @@ class SingleBackend(_Backend):
     ell_modes = ("frontier", "pallas")
 
     def solve(self, cfg, artifacts, seeds, num_seeds, warm_state=None) -> SolveOutput:
-        res = self.solve_raw(
-            cfg, artifacts["graph"], seeds, num_seeds,
-            ell=artifacts.get("ell"), init=warm_state,
-        )
-        # one explicit, batched device→host fetch (TS03 hygiene: the
-        # sanitizer forbids implicit transfers on the warm path)
-        td, ne = jax.device_get((res.tree.total_distance, res.tree.num_edges))
-        return SolveOutput(
-            total_distance=float(td),
-            num_edges=int(ne),
-            raw=res,
-            telemetry=telemetry_from_counts(
+        with obs.span("solve:dispatch"):
+            res = self.solve_raw(
+                cfg, artifacts["graph"], seeds, num_seeds,
+                ell=artifacts.get("ell"), init=warm_state,
+            )
+        # explicit device→host fetches (TS03 hygiene: the sanitizer
+        # forbids implicit transfers on the warm path)
+        with obs.span("solve:fetch"):
+            td, ne = jax.device_get(
+                (res.tree.total_distance, res.tree.num_edges)
+            )
+            telem = telemetry_from_counts(
                 res.stats.iterations,
                 res.stats.relaxations,
                 res.stats.messages,
                 res.stats.history,
                 cfg.telemetry_rounds,
-            ),
+                scan_per_round=res.stats.scan_per_round,
+            )
+        return SolveOutput(
+            total_distance=float(td), num_edges=int(ne), raw=res,
+            telemetry=telem,
         )
 
     def dispatch(
@@ -395,30 +398,30 @@ class BatchBackend(_Backend):
     ell_modes = ("pallas",)
 
     def solve(self, cfg, artifacts, seeds, num_seeds) -> SolveOutput:
-        res = self.solve_raw(
-            cfg, artifacts["graph"], seeds, num_seeds, ell=artifacts.get("ell")
-        )
-        # Lane aggregation: iterations = slowest lane, counters = sums.
-        # The vmapped while_loop freezes converged lanes' carries, so a
-        # lane-sum of the (B, H+1, 4) histories only accumulates rows
-        # each lane actually wrote.
+        with obs.span("solve:dispatch"):
+            res = self.solve_raw(
+                cfg, artifacts["graph"], seeds, num_seeds,
+                ell=artifacts.get("ell"),
+            )
+        # Lane aggregation: iterations = slowest lane, counters = sums
+        # (int64 on the host).  The vmapped while_loop freezes converged
+        # lanes' carries, so a lane-sum of the (B, H+1, 4) histories only
+        # accumulates rows each lane actually wrote.
         stats = res.stats
         # one explicit, batched device→host fetch for the whole lane
         # aggregation (TS03 hygiene — no implicit per-field syncs)
-        iterations, relaxations, messages, history, td, ne = jax.device_get(
-            (stats.iterations, stats.relaxations, stats.messages,
-             stats.history, res.tree.total_distance, res.tree.num_edges)
-        )
-        iters = int(np.max(iterations))
-        per_round = None
-        if history is not None and cfg.telemetry_rounds > 0:
-            hist = np.asarray(history).sum(axis=0)
-            per_round = hist[: min(iters, cfg.telemetry_rounds)]
-        telem = SolveTelemetry(
-            iterations=iters,
-            relaxations=int(round(float(np.sum(relaxations)))),
-            messages=int(round(float(np.sum(messages)))),
-            per_round=per_round,
+        with obs.span("solve:fetch"):
+            iterations, relaxations, messages, history, td, ne = jax.device_get(
+                (stats.iterations, stats.relaxations, stats.messages,
+                 stats.history, res.tree.total_distance, res.tree.num_edges)
+            )
+        telem = telemetry_from_counts(
+            iterations,
+            np.sum(relaxations, dtype=np.int64),
+            np.sum(messages, dtype=np.int64),
+            None if history is None else np.sum(history, axis=0, dtype=np.int64),
+            cfg.telemetry_rounds,
+            scan_per_round=stats.scan_per_round,
         )
         return SolveOutput(
             total_distance=np.asarray(td),
@@ -686,6 +689,7 @@ class Mesh1DBackend(_Backend):
                 res.history,
                 cfg.telemetry_rounds,
                 per_rank=res.per_rank,
+                scan_per_round=res.scan_per_round,
             ),
         )
 
@@ -732,7 +736,14 @@ class Mesh1DBackend(_Backend):
                 mesh, self._part_arrays(cfg, part), (*replica_axes, vert_axis)
             )
         out = fn(*edges, _place_replicated(mesh, seeds))
-        return result_from_device(out, part.n)
+        # every device reads its shard local_steps times a round, or its
+        # top-K ELL rows (frontier; K as the engine caps it)
+        per_dev = (
+            min(cfg.frontier_size, part.rb) * part.k
+            if cfg.mode == "frontier" else part.eb * cfg.local_steps
+        )
+        scan = per_dev * part.n_replica * part.n_blocks
+        return result_from_device(out, part.n, scan_per_round=scan)
 
 
 @register_backend("mesh2d")
@@ -845,6 +856,7 @@ class Mesh2DBackend(_Backend):
                 res.history,
                 cfg.telemetry_rounds,
                 per_rank=res.per_rank,
+                scan_per_round=res.scan_per_round,
             ),
         )
 
@@ -878,7 +890,9 @@ class Mesh2DBackend(_Backend):
                 mesh, (part.src_row, part.dst_col, part.w), (row_axis, col_axis)
             )
         out = fn(*edges, _place_replicated(mesh, seeds))
-        return result_from_device(out, part.n)
+        # every device reads its whole edge block each round
+        scan = part.eb * part.R * part.C
+        return result_from_device(out, part.n, scan_per_round=scan)
 
 
 # ----------------------------------------------------------------------------

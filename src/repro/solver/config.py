@@ -82,9 +82,10 @@ class SolverConfig:
       fuse_gather: pack (dist, lab) into one f32 all-gather (mesh1d).
       lab_i16: gather labels as int16 (mesh1d, |S| < 32768).
       telemetry_rounds: static H — every fixpoint loop carries a
-        (H+1, 4) per-round telemetry buffer (``repro.obs.ROUND_CHANNELS``
-        rows: frontier, messages, relaxations, unreached), surfaced as
-        ``SolveOutput.telemetry.per_round``.  Rounds beyond H spill into
+        (H+1, 4) int32 per-round telemetry buffer
+        (``repro.obs.ROUND_CHANNELS`` rows: frontier, messages,
+        relaxations, unreached), surfaced as
+        ``SolveOutput.telemetry.per_round``.  Rounds beyond H add up in
         the last slot (aggregate counters stay exact).  0 disables the
         buffer entirely.  H is baked into the executable, so toggling
         the host-side obs recorder never retraces or changes trees.

@@ -32,28 +32,38 @@ class SolveTelemetry:
     """Uniform convergence telemetry of one solve (the paper's §VI
     per-rank measurements, backend-independent).
 
-    Every backend used to expose these only through its native ``raw``
-    result, with backend-dependent dtypes (the mesh/pallas paths carried
-    f32 counters).  Here they are plain Python ints regardless of
-    backend; for the "batch" backend they aggregate over lanes
-    (iterations = max, messages/relaxations = sum).  Counters ride the
-    device loops as f32, exact for values < 2**24 (~16.7M) per solve.
+    Every counter is an exact Python int on every backend; for the
+    "batch" backend they aggregate over lanes (iterations = max, the rest
+    = sums).  The loops carry int32 rows; the totals are summed from them
+    on the host (rows and spill slot), so they are exact until a single
+    round's count or the spill sum passes 2**31 - 1, where the device
+    saturates it.  Without rows (telemetry_rounds=0) ``relaxations`` and
+    ``messages`` come from the loop's own saturating int32 totals.
 
     Attributes:
       iterations: global relaxation rounds until the fixpoint.
-      relaxations: vertex-state improvements across all rounds.
-      messages: candidate transmissions attempted ("messages", Fig. 6).
-      per_round: (R, 4) f32 array, one row per round in
+      relaxations: vertex-state improvements across all rounds, one
+        winning edge relaxation each (the same meaning in every schedule).
+      messages: the paper's message count (Fig. 6): dense, bucket and
+        pallas charge each improved vertex its out-degree; frontier,
+        pallas_frontier and the mesh engines count the finite candidates
+        they relaxed.
+      per_round: (R, 4) int32 array, one row per round in
         ``repro.obs.ROUND_CHANNELS`` order (frontier, messages,
         relaxations, unreached), R = min(iterations,
         config.telemetry_rounds); None when telemetry_rounds=0.
         Batch solves sum the buffer across lanes (converged lanes stop
         writing, so short lanes contribute zero rows).
-      per_rank: (R, n_ranks, 4) f32 flight-recorder buffer — one channel
-        row per mesh device per round, trimmed like ``per_round``; rank
-        rows sum exactly to the global channels (integer f32 counts,
-        ghost padding corrected per block).  None unless the solve ran
+      per_rank: (R, n_ranks, 4) int32 flight-recorder buffer — one
+        channel row per mesh device per round, trimmed like
+        ``per_round``; rank rows sum exactly to the global channels
+        (ghost padding corrected per block).  None unless the solve ran
         with ``SolverConfig.telemetry_per_rank=True`` (mesh backends).
+      scanned: edges the relaxation kernels read over all rounds: the
+        schedule's static edges per round × iterations (summed over a
+        batch's lanes); ``relaxations / scanned`` is the share of the
+        scan that improved a vertex.  None where the backend states no
+        per-round scan.
     """
 
     iterations: int
@@ -61,18 +71,22 @@ class SolveTelemetry:
     messages: int
     per_round: Optional[np.ndarray] = None
     per_rank: Optional[np.ndarray] = None
+    scanned: Optional[int] = None
 
 
 def telemetry_from_counts(
     iterations, relaxations, messages, history, telemetry_rounds: int,
-    per_rank=None,
+    per_rank=None, scan_per_round: Optional[int] = None,
 ) -> SolveTelemetry:
     """Builds a :class:`SolveTelemetry` from loop-carried counters.
 
-    ``history`` is the raw (H+1, 4) device buffer (or None); the spill
-    slot and rows beyond the round count are trimmed here, on the host.
-    ``per_rank`` is the raw (H+1, n_ranks, 4) flight-recorder buffer (or
-    None), trimmed identically.
+    ``iterations`` is a count, or a batch's per-lane counts; ``history``
+    is the raw (H+1, 4) buffer (or None; a batch passes its lanes' sum);
+    rows beyond the round count are trimmed here, on the host, and the
+    totals summed from the rows plus the spill slot H.  ``per_rank`` is
+    the raw (H+1, n_ranks, 4) flight-recorder buffer (or None), trimmed
+    identically.  ``scan_per_round`` is the schedule's static edges read
+    per round.
 
     This is the solve's one device→host crossing, so it is *explicit*
     (``jax.device_get``, one batched fetch) rather than five implicit
@@ -82,22 +96,32 @@ def telemetry_from_counts(
     """
     import jax
 
+    from repro.obs import ROUND_CHANNELS
+
     iterations, relaxations, messages, history, per_rank = jax.device_get(
         (iterations, relaxations, messages, history, per_rank)
     )
-    iters = int(iterations)
+    iters = int(np.max(iterations))
     per_round = None
+    totals = {"relaxations": int(relaxations), "messages": int(messages)}
     if history is not None and telemetry_rounds > 0:
-        per_round = np.asarray(history)[: min(iters, telemetry_rounds)]
+        hist = np.asarray(history)
+        per_round = hist[: min(iters, telemetry_rounds)]
+        rows = hist[: min(iters, telemetry_rounds + 1)].astype(np.int64)
+        for name in totals:
+            totals[name] = int(rows[:, ROUND_CHANNELS.index(name)].sum())
     rank_rows = None
     if per_rank is not None and telemetry_rounds > 0:
         rank_rows = np.asarray(per_rank)[: min(iters, telemetry_rounds)]
+    scanned = None
+    if scan_per_round is not None:
+        scanned = int(scan_per_round) * int(np.sum(iterations, dtype=np.int64))
     return SolveTelemetry(
         iterations=iters,
-        relaxations=int(round(float(relaxations))),
-        messages=int(round(float(messages))),
         per_round=per_round,
         per_rank=rank_rows,
+        scanned=scanned,
+        **totals,
     )
 
 

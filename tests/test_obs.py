@@ -7,12 +7,13 @@ The load-bearing guarantees:
     per-round telemetry rides every fixpoint loop unconditionally, so
     the toggle is host-side only (asserted bit-for-bit below);
   * ``SolveOutput.telemetry`` is the one uniform counter surface across
-    all backends (Python ints; mesh/pallas f32 raws normalized), and its
-    per-round rows sum exactly to the aggregate counters.
+    all backends (exact Python ints from int32 rows), and its per-round
+    rows sum exactly to the aggregate counters.
 """
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -39,6 +40,7 @@ ROOT = Path(__file__).resolve().parent.parent
 
 MSG = obs.ROUND_CHANNELS.index("messages")
 RELAX = obs.ROUND_CHANNELS.index("relaxations")
+NCH = len(obs.ROUND_CHANNELS)
 
 
 @pytest.fixture(autouse=True)
@@ -126,13 +128,15 @@ def test_tracer_span_export_and_validate(tmp_path):
     tr = Tracer()
     with tr.span("outer", mode="frontier"):
         t0 = tr.now()
-        tr.add_instant("checkpoint")
     tr.add_span("retro", t0, tr.now(), round=0)
-    tr.add_counter("convergence", tr.now(), {"frontier": 5.0})
+    tr.add_counter("convergence", tr.now(), {"frontier": 5, "share": 0.5})
     path = tmp_path / "trace.json"
     tr.export_chrome(str(path))
     doc = json.loads(path.read_text())
-    assert validate_chrome_trace(doc) == 4
+    assert validate_chrome_trace(doc) == 3
+    conv = [e for e in doc["traceEvents"] if e["name"] == "convergence"][0]
+    assert conv["args"] == {"frontier": 5, "share": 0.5}
+    assert isinstance(conv["args"]["frontier"], int)
     names = [e["name"] for e in doc["traceEvents"]]
     assert "process_name" in names and "outer" in names and "retro" in names
 
@@ -163,7 +167,8 @@ def test_disabled_by_default_everything_noops(tmp_path):
     with obs.span("never-recorded"):
         pass
     obs.add_span("retro", 0.0, 1.0)
-    obs.emit_round_telemetry(np.ones((2, 4)), 0.0, 1.0, label="x")
+    obs.emit_round_telemetry(np.ones((2, NCH)), 0.0, 1.0, label="x")
+    obs.add_counter("x", 0.0, {"a": 1})
     assert obs.prometheus_text() == ""
     assert obs.export_chrome_trace(str(tmp_path / "t.json")) is False
 
@@ -244,9 +249,12 @@ def test_telemetry_matches_raw_counters(backend, mode):
     assert t.messages == int(round(float(raw_msg)))
     assert t.relaxations == int(round(float(raw_rx)))
     # per-round rows (ROUND_CHANNELS order) sum exactly to the aggregates
-    assert t.per_round is not None and t.per_round.shape == (t.iterations, 4)
+    assert t.per_round is not None and t.per_round.shape == (t.iterations, NCH)
+    assert t.per_round.dtype == np.int32
     assert int(t.per_round[:, MSG].sum()) == t.messages
     assert int(t.per_round[:, RELAX].sum()) == t.relaxations
+    # the scan is a static count per round, times the rounds
+    assert t.scanned > 0 and t.scanned % t.iterations == 0
 
 
 def test_batch_telemetry_aggregates_lanes():
@@ -270,8 +278,9 @@ def test_batch_telemetry_aggregates_lanes():
     assert t.iterations == max(s.telemetry.iterations for s in singles)
     assert t.messages == sum(s.telemetry.messages for s in singles)
     assert t.relaxations == sum(s.telemetry.relaxations for s in singles)
-    assert t.per_round.shape == (t.iterations, 4)
+    assert t.per_round.shape == (t.iterations, NCH)
     assert int(t.per_round[:, MSG].sum()) == t.messages
+    assert t.scanned == sum(s.telemetry.scanned for s in singles)
 
 
 def test_telemetry_rounds_spill_and_zero():
@@ -293,7 +302,9 @@ def test_telemetry_rounds_spill_and_zero():
     )
     assert small.telemetry.iterations == iters
     assert small.telemetry.messages == full.telemetry.messages
-    assert small.telemetry.per_round.shape == (3, 4)
+    assert small.telemetry.relaxations == full.telemetry.relaxations
+    assert small.telemetry.scanned == full.telemetry.scanned
+    assert small.telemetry.per_round.shape == (3, NCH)
     assert np.array_equal(small.telemetry.per_round, full.telemetry.per_round[:3])
     # H=0: no buffer at all, identical trees and counters
     off = (
@@ -306,6 +317,7 @@ def test_telemetry_rounds_spill_and_zero():
     assert off.telemetry.per_round is None
     assert off.total_distance == full.total_distance
     assert off.telemetry.messages == full.telemetry.messages
+    assert off.telemetry.scanned == full.telemetry.scanned
 
 
 def test_solve_emits_spans_and_convergence_tracks(tmp_path):
@@ -322,12 +334,24 @@ def test_solve_emits_spans_and_convergence_tracks(tmp_path):
     names = {e["name"] for e in doc["traceEvents"]}
     assert "prepare" in names and "solve" in names
     assert "prepare:ell_build" in names
-    assert any(n.startswith("round[") for n in names)
-    assert any(n.startswith("convergence[") for n in names)
-    rounds = [
-        e for e in doc["traceEvents"] if e["name"].startswith("round[")
+    assert "solve:dispatch" in names and "solve:fetch" in names
+    # per-round telemetry is counter tracks only: no spans with made-up times
+    assert not any(n.startswith("round[") for n in names)
+    assert not any(
+        "synthetic_timing" in e.get("args", {}) for e in doc["traceEvents"]
+    )
+    conv = [
+        e for e in doc["traceEvents"]
+        if e["name"] == "convergence[single/frontier]"
     ]
-    assert all(e["args"]["synthetic_timing"] for e in rounds)
+    assert conv and all(e["ph"] == "C" for e in conv)
+    assert set(conv[0]["args"]) == set(obs.ROUND_CHANNELS)
+    totals = [
+        e for e in doc["traceEvents"]
+        if e["name"] == "solve_totals[single/frontier]"
+    ]
+    assert len(totals) == 1
+    assert totals[0]["args"]["messages"] == sum(e["args"]["messages"] for e in conv)
     samples = parse_prometheus(obs.prometheus_text())
     assert any(k.startswith("solver_messages_total") for k in samples)
     assert any(k.startswith("solver_solve_seconds_count") for k in samples)
@@ -377,6 +401,7 @@ def test_serve_emits_query_spans():
     srv.flush()
     names = {e["name"] for e in obs.tracer().events()}
     assert {
+        "serve:flush",
         "serve:queue_wait",
         "serve:assemble",
         "serve:solve",
@@ -606,7 +631,7 @@ def test_per_rank_flight_recorder_single_device(backend, mode):
     )
     pr = out.telemetry.per_rank
     assert pr is not None
-    assert pr.shape == (base.telemetry.per_round.shape[0], 1, 4)
+    assert pr.shape == (base.telemetry.per_round.shape[0], 1, NCH)
     flight.check_consistency(pr, out.telemetry.per_round)
     # the knob is observability-only
     np.testing.assert_array_equal(
@@ -650,7 +675,7 @@ def test_per_rank_emits_rank_counter_tracks(tmp_path):
 
 
 def test_flight_imbalance_and_stragglers():
-    per_rank = np.zeros((3, 4, 4), np.float32)
+    per_rank = np.zeros((3, 4, NCH), np.int32)
     per_rank[0, :, MSG] = [4, 0, 0, 0]  # one rank does everything
     per_rank[1, :, MSG] = [1, 1, 1, 1]  # perfectly balanced
     # round 2: no activity at all → imbalance 1.0 by definition
@@ -672,11 +697,11 @@ def test_flight_imbalance_and_stragglers():
 
 
 def test_flight_consistency_check():
-    per_rank = np.arange(2 * 3 * 4, dtype=np.float32).reshape(2, 3, 4)
+    per_rank = np.arange(2 * 3 * NCH, dtype=np.int32).reshape(2, 3, NCH)
     per_round = per_rank.sum(axis=1)
     flight.check_consistency(per_rank, per_round)  # exact → no raise
     bad = per_round.copy()
-    bad[1, MSG] += 1.0
+    bad[1, MSG] += 1
     with pytest.raises(ValueError, match="round 1"):
         flight.check_consistency(per_rank, bad, label="unit")
     with pytest.raises(ValueError, match="per_rank must be"):
@@ -684,8 +709,8 @@ def test_flight_consistency_check():
 
 
 def test_flight_dump_load_render(tmp_path):
-    per_rank = np.ones((2, 2, 4), np.float32)
-    per_rank[1, 0, MSG] = 5.0
+    per_rank = np.ones((2, 2, NCH), np.int32)
+    per_rank[1, 0, MSG] = 5
     path = tmp_path / "flight.json"
     flight.dump_flight(
         str(path), per_rank, label="t", per_round=per_rank.sum(axis=1),
@@ -708,7 +733,7 @@ def test_flight_dump_load_render(tmp_path):
 
 
 def test_obs_cli_report(tmp_path, capsys):
-    per_rank = np.ones((2, 2, 4), np.float32)
+    per_rank = np.ones((2, 2, NCH), np.int32)
     path = tmp_path / "flight.json"
     flight.dump_flight(
         str(path), per_rank, label="t", per_round=per_rank.sum(axis=1)
@@ -853,3 +878,227 @@ def test_bench_cli_gate(tmp_path, monkeypatch):
     assert obs_main(args) == 1
     rows = regress.load_history(hist)
     assert len(rows) == 5 and rows[-1]["injected"] == 2.0
+
+
+# ----------------------------------------------------------------------------
+# stage scopes, profiler-clock spans, exact integer telemetry
+# ----------------------------------------------------------------------------
+
+STAGE_SCOPES = ("voronoi", "distance_graph", "mst", "extract")
+
+
+@pytest.mark.parametrize(
+    "backend,mode",
+    [("single", "bucket"), ("single", "frontier"), ("batch", "bucket"),
+     ("mesh1d", "bucket")],
+)
+def test_lowered_hlo_holds_stage_scopes(backend, mode):
+    from repro.solver.backends import trace_for_analysis
+
+    g, n, seeds = _instance(0)
+    cfg = SolverConfig(backend=backend, mode=mode, mesh_shape=(1, 1))
+    text = trace_for_analysis(cfg, g, seeds).lower().as_text(debug_info=True)
+    for scope in STAGE_SCOPES:
+        # a vmapped stage reads vmap(<scope>)/...
+        assert re.search(rf"\b{scope}\)?/", text), scope
+    if backend == "mesh1d":
+        assert "voronoi/while/body/exchange/" in text
+
+
+def test_enabled_span_is_a_profiler_annotation(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    obs.enable(trace=True, metrics=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("solve"):
+            jnp.arange(8).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    obs.disable()
+    with obs.span("never-annotated"):
+        pass
+    pb = next(tmp_path.rglob("*.xplane.pb"))
+    host = [
+        e.name for p in ProfileData.from_file(str(pb)).planes
+        if p.name.startswith("/host") for line in p.lines for e in line.events
+    ]
+    assert "solve" in host and "never-annotated" not in host
+    assert [e["name"] for e in obs.tracer().events() if e["name"] == "solve"] == ["solve"]
+
+
+def test_gc_while_tracing_records_a_span():
+    import gc
+
+    gc.collect()  # obs off: no callback, nothing recorded
+    obs.enable(trace=True, metrics=False)
+    gc.collect()
+    obs.disable()
+    gc.collect()
+    spans = [e for e in obs.tracer().events() if e["name"] == "gc"]
+    assert spans and spans[-1]["args"]["generation"] == 2
+    assert all(e["args"]["generation"] in (0, 1, 2) for e in spans)
+    assert obs.tracer() is not None and not any(
+        cb.__module__ == "repro.obs" for cb in gc.callbacks
+    )
+
+
+def test_hist_write_is_exact_int32_with_a_saturating_spill():
+    import jax.numpy as jnp
+
+    from repro.core.voronoi import I32_MAX, UNREACHED, _hist_write, hist_init, sat_add
+
+    hist = hist_init(2)
+    assert hist.dtype == jnp.int32 and hist.shape == (3, NCH)
+    big = jnp.full((NCH,), 2**24 + 1, jnp.int32)
+    hist = _hist_write(hist, jnp.int32(0), big)
+    assert int(hist[0, MSG]) == 2**24 + 1  # an f32 row would read 2**24
+    # rounds >= H add up in the spill slot; unreached keeps the latest
+    row = jnp.arange(1, NCH + 1, dtype=jnp.int32)
+    hist = _hist_write(hist, jnp.int32(2), row)
+    hist = _hist_write(hist, jnp.int32(5), row)
+    spill = np.asarray(hist[2])
+    assert spill[MSG] == 2 * (MSG + 1) and spill[UNREACHED] == UNREACHED + 1
+    # ... and hold at 2**31 - 1 instead of wrapping
+    huge = jnp.full((NCH,), I32_MAX - 1, jnp.int32)
+    hist = _hist_write(hist, jnp.int32(3), huge)
+    hist = _hist_write(hist, jnp.int32(4), huge)
+    assert int(hist[2, MSG]) == I32_MAX
+    assert int(sat_add(jnp.int32(I32_MAX - 3), jnp.int32(10))) == I32_MAX
+    assert int(sat_add(jnp.int32(7), jnp.int32(10))) == 17
+
+
+def test_scanned_channel_bucket_and_frontier():
+    g, n, seeds = _instance(1)
+    bucket = (
+        SteinerSolver(SolverConfig(backend="single", mode="bucket"))
+        .prepare(g)
+        .solve(seeds)
+    )
+    # dense/bucket read the whole (padded) edge array every round
+    assert bucket.telemetry.per_round.dtype == np.int32
+    assert bucket.raw.stats.scan_per_round == g.src.shape[0]
+    assert bucket.telemetry.scanned == g.src.shape[0] * bucket.telemetry.iterations
+    cfg = SolverConfig(
+        backend="single", mode="frontier", ell_width=4, frontier_size=8
+    )
+    handle = SteinerSolver(cfg).prepare(g)
+    front = handle.solve(seeds)
+    R, k = handle.artifact("ell").nbr.shape
+    # frontier reads its K selected rows' k lanes every round
+    assert front.raw.stats.scan_per_round == min(8, R) * k
+    assert front.telemetry.scanned == min(8, R) * k * front.telemetry.iterations
+    assert 0 < front.telemetry.relaxations <= front.telemetry.scanned
+
+
+def _path_graph(n):
+    """A unit-weight path 0 - 1 - ... - n-1: every schedule first reaches a
+    vertex from its nearest seed, so it improves each vertex exactly once."""
+    src = np.arange(n - 1, dtype=np.int32)
+    return from_edges(src, src + 1, np.ones(n - 1, np.float32), n, pad_to=8)
+
+
+@pytest.mark.parametrize(
+    "backend,mode",
+    [("single", "dense"), ("single", "bucket"), ("single", "frontier"),
+     ("single", "pallas"), ("mesh1d", "bucket"), ("mesh1d", "frontier"),
+     ("mesh2d", "bucket")],
+)
+def test_relaxations_mean_the_same_in_every_schedule(backend, mode):
+    """``relaxations / scanned`` (``relax_useful_pct``) compares schedules:
+    on a path each reached vertex improves once under any schedule, so
+    the numerator is the same and only the scan differs."""
+    n = 40
+    g = _path_graph(n)
+    seeds = np.array([0, 23], np.int32)
+    cfg = SolverConfig(backend=backend, mode=mode, mesh_shape=(1, 1),
+                       ell_width=4, frontier_size=8)
+    t = SteinerSolver(cfg).prepare(g).solve(seeds).telemetry
+    assert t.relaxations == n - len(seeds)
+    assert t.relaxations <= t.scanned and t.scanned % t.iterations == 0
+
+
+def test_gc_while_the_tracer_holds_its_lock_does_not_deadlock():
+    """A collection can start inside the tracer's own critical section (any
+    allocation can trigger one); its ``gc`` span must still be recorded.
+    Run in a child process, so that a deadlock fails the test instead of
+    hanging the run."""
+    prog = (
+        "import gc\n"
+        "from repro import obs\n"
+        "obs.enable(trace=True, metrics=False)\n"
+        "tr = obs.tracer()\n"
+        "with tr._lock:\n"
+        "    gc.collect()\n"
+        "obs.disable()\n"
+        "assert any(e['name'] == 'gc' for e in tr.events())\n"
+        "print('ok')\n"
+    )
+    env = {**os.environ, "PYTHONPATH": str(ROOT / "src")}
+    try:
+        r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                           text=True, env=env, timeout=60)
+    except subprocess.TimeoutExpired:
+        pytest.fail("the gc callback deadlocked on the tracer lock")
+    assert r.returncode == 0 and r.stdout.strip() == "ok", r.stderr
+
+
+def test_mesh_counts_saturate_instead_of_wrapping():
+    """On a forced four-device host mesh: the devices' message counts sum
+    exactly up to 2**31 - 1 and hold there; a mesh1d solve with
+    local_steps > 1 keeps exact rows and its scan count on the host; and
+    a partition whose round scans 2**32 edges still traces (the scan is a
+    host int, not an int32 on the device)."""
+    prog = r'''
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
+from repro import obs
+from repro.core import from_edges
+from repro.core.dist_steiner import DistSteinerConfig, make_dist_steiner, sat_psum
+from repro.core.voronoi import I32_MAX
+from repro.solver import SolverConfig, SteinerSolver
+
+mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+ax = ("data", "model")
+f = jax.jit(jax.shard_map(lambda x: sat_psum(x[0], ax)[None], mesh=mesh,
+                          in_specs=P(ax), out_specs=P(ax)))
+def total(xs):
+    return int(f(jnp.array(xs, jnp.int32))[0])
+assert total([2**30, 2**30 - 1, 0, 0]) == I32_MAX  # fits exactly
+assert total([2**30, 2**30, 0, 0]) == I32_MAX  # 2**31: held, not wrapped
+assert total([I32_MAX] * 4) == I32_MAX
+assert total([70000, 70001, 65535, 1]) == 205537
+
+rng = np.random.default_rng(3)
+n = 64
+src = rng.integers(0, n, 400).astype(np.int32)
+dst = rng.integers(0, n, 400).astype(np.int32)
+w = rng.integers(1, 20, 400).astype(np.float32)
+g = from_edges(src, dst, w, n, pad_to=8)
+seeds = np.array([1, 17, 40], np.int32)
+cfg = SolverConfig(backend="mesh1d", mode="bucket", mesh_shape=(2, 2), local_steps=4)
+h = SteinerSolver(cfg).prepare(g)
+t = h.solve(seeds).telemetry
+part = h.artifact("part")
+msg = obs.ROUND_CHANNELS.index("messages")
+assert t.per_round.dtype == np.int32
+assert int(t.per_round[:, msg].sum()) == t.messages > 0
+assert t.scanned == part.eb * 4 * 4 * t.iterations
+
+nb = 1024
+dcfg = DistSteinerConfig(n=2 * nb, nb=nb, num_seeds=3, mode="bucket",
+                         local_steps=2, telemetry_rounds=8)
+edges = 4 * 2**29  # a round reads 2**31 edges, twice
+spec = lambda dt: jax.ShapeDtypeStruct((edges,), dt)
+make_dist_steiner(mesh, dcfg).lower(
+    spec(jnp.int32), spec(jnp.int32), spec(jnp.float32),
+    jax.ShapeDtypeStruct((3,), jnp.int32))
+print("ok")
+'''
+    env = {**os.environ, "PYTHONPATH": str(ROOT / "src"),
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, env=env, timeout=600)
+    assert r.returncode == 0 and r.stdout.strip().endswith("ok"), r.stderr
