@@ -78,10 +78,11 @@ TRACING_DECORATORS = frozenset(
 
 # attribute names that are Python scalars / aux metadata even on traced
 # containers — ``g.n`` is a host int carried on the jitted EllGraph pytree
-# (hashable aux data), ``x.shape`` is always static under jit
+# (hashable aux data), ``st.num_labels`` the static label count on a
+# VoronoiState, ``x.shape`` is always static under jit
 STATIC_ATTRS = frozenset(
     {"shape", "ndim", "dtype", "size", "n", "nb", "nf", "num_edges",
-     "width", "rows", "n_local", "n_pad"}
+     "width", "rows", "n_local", "n_pad", "num_labels"}
 )
 
 # builtins whose result is static when every argument is static

@@ -49,6 +49,12 @@ class VoronoiState:
     dist: jax.Array  # (N,) f32
     lab: jax.Array  # (N,) i32; == S for unreached
     pred: jax.Array  # (N,) i32; == v for seeds / unreached
+    # static label count S, set inside the dense/bucket loop: every label
+    # is at most S, so relax_dense may pack its (lab, src) tie-break into
+    # one int32 key (segmin_passes).  None: the three-pass tie-break.
+    num_labels: Optional[int] = dataclasses.field(
+        default=None, metadata=dict(static=True)
+    )
 
 
 @jax.tree_util.register_dataclass
@@ -71,6 +77,9 @@ class VoronoiStats:
     # static: edges the schedule's kernel reads each round (the whole edge
     # array, or the rows it expands); × iterations = the solve's scan
     scan_per_round: int = dataclasses.field(default=0, metadata=dict(static=True))
+    # static: segment-min passes a round makes (2 with the packed (lab, src)
+    # key, else 3); × iterations = SolveTelemetry.segmin_scatters
+    segmin_passes: int = dataclasses.field(default=0, metadata=dict(static=True))
 
 
 # Telemetry rows: obs.ROUND_CHANNELS order, int32 counts.  On one device a
@@ -136,16 +145,57 @@ def init_state(n: int, seeds: jax.Array) -> VoronoiState:
     return VoronoiState(dist=dist, lab=lab, pred=pred)
 
 
+def segmin_passes(n: int, num_labels: Optional[int]) -> int:
+    """Segment-min passes :func:`lex_segment_argmin` makes (static): 2
+    where its packed key fits, ``(S + 1) * n <= 2**31 - 1``; else 3."""
+    return 2 if num_labels is not None and (num_labels + 1) * n <= I32_MAX else 3
+
+
+def lex_segment_argmin(
+    cand: jax.Array,
+    lab_src: jax.Array,
+    src: jax.Array,
+    dst: jax.Array,
+    n: int,
+    num_labels: Optional[int] = None,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Per-vertex lexicographic minimum of ``(cand, lab_src, src)`` over
+    the edges into it: ``(m, minlab, minsrc)``, each (n,).  A vertex no
+    edge enters gets ``(+inf, I32_MAX, I32_MAX)``.
+
+    With ``num_labels`` S stated and ``(S + 1) * n <= 2**31 - 1``, the
+    (lab, src) tie-break is one int32 key ``lab * n + src`` (src < n, so
+    its order is the lexicographic one) and one segment-min finds it; else
+    a second pass finds the least label and a third the least source.
+    """
+    m = jax.ops.segment_min(cand, dst, n)
+    elig = cand == m[dst]
+    # every key lab * n + src < (S + 1) * n stays below the I32_MAX sentinel
+    if num_labels is not None and (num_labels + 1) * n <= I32_MAX:
+        key = jax.ops.segment_min(
+            jnp.where(elig, lab_src * n + src, I32_MAX), dst, n
+        )
+        none = key == I32_MAX
+        minlab = jnp.where(none, I32_MAX, key // n)
+        minsrc = jnp.where(none, I32_MAX, key % n)
+        return m, minlab, minsrc
+    minlab = jax.ops.segment_min(jnp.where(elig, lab_src, I32_MAX), dst, n)
+    elig2 = elig & (lab_src == minlab[dst])
+    minsrc = jax.ops.segment_min(jnp.where(elig2, src, I32_MAX), dst, n)
+    return m, minlab, minsrc
+
+
 def relax_dense(
     g: Graph,
     st: VoronoiState,
     active_cand: Optional[jax.Array] = None,
-) -> tuple[VoronoiState, jax.Array, jax.Array]:
+) -> tuple[VoronoiState, jax.Array]:
     """One synchronous relaxation over the (masked) edge list.
 
     Args:
       g: COO graph (padded edges carry +inf weight).
-      st: current state.
+      st: current state; its static ``num_labels`` decides whether the
+        tie-break takes two segment-min passes or three.
       active_cand: optional (E,) f32 candidate override; default
         ``dist[src] + w``. Callers mask inactive edges with +inf.
 
@@ -154,20 +204,9 @@ def relax_dense(
       (dist, lab, pred) strictly improved this round (callers derive the
       improved/attempted counts from it).
     """
-    n = g.n
-    S_sentinel = jnp.int32(jnp.iinfo(jnp.int32).max)
     cand = st.dist[g.src] + g.w if active_cand is None else active_cand
-    lab_src = st.lab[g.src]
-
-    # Lexicographic 3-pass segment argmin on (cand, lab, src).
-    m = jax.ops.segment_min(cand, g.dst, n)
-    elig1 = cand == m[g.dst]
-    minlab = jax.ops.segment_min(
-        jnp.where(elig1, lab_src, S_sentinel), g.dst, n
-    )
-    elig2 = elig1 & (lab_src == minlab[g.dst])
-    minsrc = jax.ops.segment_min(
-        jnp.where(elig2, g.src, S_sentinel), g.dst, n
+    m, minlab, minsrc = lex_segment_argmin(
+        cand, st.lab[g.src], g.src, g.dst, g.n, st.num_labels
     )
 
     # Strict lexicographic improvement on (dist, lab, pred); finite only.
@@ -176,7 +215,8 @@ def relax_dense(
         | ((m == st.dist) & (minlab < st.lab))
         | ((m == st.dist) & (minlab == st.lab) & (minsrc < st.pred))
     )
-    new = VoronoiState(
+    new = dataclasses.replace(
+        st,
         dist=jnp.where(upd, m, st.dist),
         lab=jnp.where(upd, minlab, st.lab),
         pred=jnp.where(upd, minsrc, st.pred),
@@ -268,10 +308,15 @@ def _voronoi_cells(
     init: Optional[VoronoiState] = None,
 ) -> tuple[VoronoiState, VoronoiStats]:
     n = g.n
+    S = seeds.shape[0]
     cap = jnp.int32(min(max_iters if max_iters is not None else 4 * n + 64, 2**31 - 2))
     # a warm init has a different pytree structure than None, so the warm
     # path compiles its own executable and the cold path never retraces
     st0 = init_state(n, seeds) if init is None else init
+    # labels are at most S (the unreached sentinel): relax_dense may pack
+    # its tie-break; the returned state states no label count, as before
+    st0 = dataclasses.replace(st0, num_labels=S)
+    passes = segmin_passes(n, S)
     hist0 = hist_init(telemetry_rounds)
     # out-degree: an improved vertex "sends a message" to every neighbor
     # (the paper's generated-message-traffic metric, Fig. 6)
@@ -304,12 +349,13 @@ def _voronoi_cells(
         st, iters, rlx, msg, _, hist = jax.lax.while_loop(
             cond, body, (st0, zero, zero, zero, jnp.bool_(True), hist0)
         )
-        return st, VoronoiStats(
+        return dataclasses.replace(st, num_labels=None), VoronoiStats(
             iterations=iters,
             relaxations=rlx,
             messages=msg,
             history=hist if telemetry_rounds > 0 else None,
             scan_per_round=scanned,
+            segmin_passes=passes,
         )
 
     if mode == "bucket":
@@ -367,12 +413,13 @@ def _voronoi_cells(
                 hist0,
             ),
         )
-        return st, VoronoiStats(
+        return dataclasses.replace(st, num_labels=None), VoronoiStats(
             iterations=iters,
             relaxations=rlx,
             messages=msg,
             history=hist if telemetry_rounds > 0 else None,
             scan_per_round=scanned,
+            segmin_passes=passes,
         )
 
     raise ValueError(
@@ -511,4 +558,5 @@ def voronoi_cells_frontier(
         messages=msg,
         history=hist if telemetry_rounds > 0 else None,
         scan_per_round=scanned,
+        segmin_passes=3,
     )

@@ -161,6 +161,7 @@ def voronoi_cells_pallas(
         messages=msg,
         history=hist if telemetry_rounds > 0 else None,
         scan_per_round=scanned,
+        segmin_passes=3,
     )
 
 
@@ -283,4 +284,5 @@ def voronoi_cells_pallas_frontier(
         messages=msg,
         history=hist if telemetry_rounds > 0 else None,
         scan_per_round=scanned,
+        segmin_passes=3,
     )

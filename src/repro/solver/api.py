@@ -204,7 +204,8 @@ class PreparedGraph:
             # the solve's exact totals, one sample per solve
             t = out.telemetry
             totals = {"iterations": t.iterations, "messages": t.messages,
-                      "relaxations": t.relaxations, "scanned": t.scanned}
+                      "relaxations": t.relaxations, "scanned": t.scanned,
+                      "segmin_scatters": t.segmin_scatters}
             obs.add_counter(
                 f"solve_totals[{label}]", t1,
                 {k: v for k, v in totals.items() if v is not None},

@@ -314,6 +314,7 @@ class SingleBackend(_Backend):
                 res.stats.history,
                 cfg.telemetry_rounds,
                 scan_per_round=res.stats.scan_per_round,
+                segmin_passes=res.stats.segmin_passes,
             )
         return SolveOutput(
             total_distance=float(td), num_edges=int(ne), raw=res,
@@ -422,6 +423,7 @@ class BatchBackend(_Backend):
             None if history is None else np.sum(history, axis=0, dtype=np.int64),
             cfg.telemetry_rounds,
             scan_per_round=stats.scan_per_round,
+            segmin_passes=stats.segmin_passes,
         )
         return SolveOutput(
             total_distance=np.asarray(td),

@@ -64,6 +64,11 @@ class SolveTelemetry:
         batch's lanes); ``relaxations / scanned`` is the share of the
         scan that improved a vertex.  None where the backend states no
         per-round scan.
+      segmin_scatters: the relaxation's segment-min passes over all
+        rounds: the schedule's static passes per round (2 with the packed
+        (lab, src) tie-break key, else 3) × iterations, summed over a
+        batch's lanes like ``scanned``.  None where the backend states no
+        passes (the mesh engines).
     """
 
     iterations: int
@@ -72,11 +77,13 @@ class SolveTelemetry:
     per_round: Optional[np.ndarray] = None
     per_rank: Optional[np.ndarray] = None
     scanned: Optional[int] = None
+    segmin_scatters: Optional[int] = None
 
 
 def telemetry_from_counts(
     iterations, relaxations, messages, history, telemetry_rounds: int,
     per_rank=None, scan_per_round: Optional[int] = None,
+    segmin_passes: Optional[int] = None,
 ) -> SolveTelemetry:
     """Builds a :class:`SolveTelemetry` from loop-carried counters.
 
@@ -86,7 +93,8 @@ def telemetry_from_counts(
     totals summed from the rows plus the spill slot H.  ``per_rank`` is
     the raw (H+1, n_ranks, 4) flight-recorder buffer (or None), trimmed
     identically.  ``scan_per_round`` is the schedule's static edges read
-    per round.
+    per round, ``segmin_passes`` its static segment-min passes per round
+    (0 or None: not stated).
 
     This is the solve's one device→host crossing, so it is *explicit*
     (``jax.device_get``, one batched fetch) rather than five implicit
@@ -113,14 +121,19 @@ def telemetry_from_counts(
     rank_rows = None
     if per_rank is not None and telemetry_rounds > 0:
         rank_rows = np.asarray(per_rank)[: min(iters, telemetry_rounds)]
+    lane_rounds = int(np.sum(iterations, dtype=np.int64))
     scanned = None
     if scan_per_round is not None:
-        scanned = int(scan_per_round) * int(np.sum(iterations, dtype=np.int64))
+        scanned = int(scan_per_round) * lane_rounds
+    segmin_scatters = None
+    if segmin_passes:
+        segmin_scatters = int(segmin_passes) * lane_rounds
     return SolveTelemetry(
         iterations=iters,
         per_round=per_round,
         per_rank=rank_rows,
         scanned=scanned,
+        segmin_scatters=segmin_scatters,
         **totals,
     )
 

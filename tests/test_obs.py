@@ -993,6 +993,47 @@ def test_scanned_channel_bucket_and_frontier():
     assert 0 < front.telemetry.relaxations <= front.telemetry.scanned
 
 
+def test_segmin_scatters_counts_the_passes(tmp_path):
+    """Bucket rounds take two segment-min passes (the packed (lab, src)
+    key), frontier rounds three; the batch sums its lanes' rounds; the
+    ``solve_totals[...]`` sample carries the count."""
+    g, n, seeds = _instance(1)
+    obs.enable()
+    bucket = (
+        SteinerSolver(SolverConfig(backend="single", mode="bucket"))
+        .prepare(g)
+        .solve(seeds)
+    )
+    t = bucket.telemetry
+    assert bucket.raw.stats.segmin_passes == 2
+    assert t.segmin_scatters == 2 * t.iterations > 0
+    front = (
+        SteinerSolver(SolverConfig(backend="single", mode="frontier",
+                                   ell_width=4, frontier_size=8))
+        .prepare(g)
+        .solve(seeds)
+    )
+    assert front.telemetry.segmin_scatters == 3 * front.telemetry.iterations
+    lanes = np.stack([seeds, seeds[::-1]]).astype(np.int32)
+    batch = (
+        SteinerSolver(SolverConfig(backend="batch", mode="bucket"))
+        .prepare(g)
+        .solve(lanes)
+    )
+    lane_rounds = np.asarray(batch.raw.stats.iterations)
+    assert batch.telemetry.segmin_scatters == 2 * int(lane_rounds.sum())
+    path = tmp_path / "trace.json"
+    assert obs.export_chrome_trace(str(path))
+    events = json.loads(path.read_text())["traceEvents"]
+    totals = {e["name"]: e["args"] for e in events
+              if e["name"].startswith("solve_totals[")}
+    assert totals["solve_totals[single/bucket]"]["segmin_scatters"] == 2 * t.iterations
+    assert totals["solve_totals[single/frontier]"]["segmin_scatters"] == (
+        front.telemetry.segmin_scatters)
+    assert totals["solve_totals[batch/bucket]"]["segmin_scatters"] == (
+        batch.telemetry.segmin_scatters)
+
+
 def _path_graph(n):
     """A unit-weight path 0 - 1 - ... - n-1: every schedule first reaches a
     vertex from its nearest seed, so it improves each vertex exactly once."""
