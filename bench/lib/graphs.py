@@ -8,6 +8,16 @@ configuration: they are drawn in fixed blocks of ``BLOCK_EDGES``, block ``i``
 from ``SeedSequence((graph_seed, 2 + i))``.  The run's seed draws Graph500's
 permutation of the vertex ids, from ``SeedSequence((seed, 0))``.  So every
 run solves the same graph under other names: the same work, other inputs.
+
+A configuration may set ``id_blocks`` (``B``, dividing ``n``), the number
+of contiguous blocks of ``n / B`` ids over which the system under test
+spreads the vertices, one block a chip.  A deployment partitions its graph
+once, when it loads it, so which vertices share a block is fixed with the
+graph: a permutation ``base`` from ``SeedSequence((graph_seed, 1))`` puts
+each vertex in its block, and the run's seed then only renames the ids
+inside each block, one permutation of each block from ``SeedSequence((seed,
+0))``.  Every run then gives each block the same vertices and edges.
+Without it (or with 1) the run's seed permutes all ids, as Graph500 does.
 Unlike the program's default, no connecting path is added: the graph is the
 Graph500 one, with its many small components, and queries draw their seeds
 from the largest.
@@ -70,14 +80,29 @@ def largest_component(n: int, src: np.ndarray, dst: np.ndarray) -> np.ndarray:
 
 def make_graph(spec: dict, seed: int):
     """``(src, dst, w, n, component)``: the configuration's graph with its ids
-    permuted by ``seed``, and its largest component, in the order of the ids
-    before the permutation, so that a draw by position picks the same
-    vertices under every seed's names."""
+    permuted by ``seed`` (``id_permutation``), and its largest component, in
+    the order of the ids before the permutation, so that a draw by position
+    picks the same vertices under every seed's names."""
     if spec["generator"] != "graph500_rmat":
         raise ValueError(f"unknown graph generator {spec['generator']!r}")
     src, dst, w, n = rmat(spec["scale"], spec["edge_factor"], a=spec["a"], b=spec["b"],
                           c=spec["c"], max_weight=spec["max_weight"], seed=spec["graph_seed"])
     component = largest_component(n, src, dst)
-    perm = np.random.default_rng(np.random.SeedSequence((seed, 0))).permutation(n)
-    perm = perm.astype(np.int32)
+    perm = id_permutation(n, int(spec.get("id_blocks", 1)), spec["graph_seed"], seed)
     return perm[src], perm[dst], w, n, perm[component]
+
+
+def id_permutation(n: int, blocks: int, graph_seed: int, seed: int) -> np.ndarray:
+    """The new id of each vertex: a permutation of all ``n`` ids by ``seed``
+    where ``blocks`` is 1, else ``local[base]``, ``base`` a permutation of
+    all ids by ``graph_seed`` and ``local`` one by ``seed`` of each block of
+    ``n / blocks`` ids, so that ``perm[v] // (n / blocks)`` is fixed by
+    ``graph_seed`` alone."""
+    rng = np.random.default_rng(np.random.SeedSequence((seed, 0)))
+    if blocks == 1:
+        return rng.permutation(n).astype(np.int32)
+    if blocks < 1 or n % blocks:
+        raise ValueError(f"id_blocks={blocks} does not divide {n} vertices into equal blocks")
+    base = np.random.default_rng(np.random.SeedSequence((graph_seed, 1))).permutation(n)
+    local = rng.permuted(np.arange(n).reshape(blocks, n // blocks), axis=1).reshape(n)
+    return local[base].astype(np.int32)

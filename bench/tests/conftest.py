@@ -19,22 +19,27 @@ for p in (str(ROOT), str(ROOT / "src")):
 
 def with_waiting_cells(bench: dict) -> dict:
     """``bench`` with the cells built but not yet in ``BENCHMARK.json``
-    (``data/waiting_cells.json``: the four-chip mesh, 10,240 seeds), so
-    that the tests drive every loop and system.  The benchmark change that
-    adds those cells deletes the file and this merge."""
+    (``data/waiting_cells.json``: 10,240 seeds), so that the tests drive
+    every loop and system.  Only what ``bench`` lacks is merged, so a
+    benchmark change that adds a waiting cell leaves no double; the one
+    that adds the last deletes the file and this merge."""
     waiting = json.loads((Path(__file__).parent / "data" / "waiting_cells.json").read_text())
-    have = {w["name"] for w in bench["workloads"]}
-    if all(w["name"] in have for w in waiting["workloads"]):
+
+    def lacking(key):
+        have = {e["name"] for e in bench[key]}
+        return [e for e in waiting[key] if e["name"] not in have]
+
+    new = {w["name"] for w in lacking("workloads")}
+    if not new:
         return bench
-    bench["configs"] += waiting["configs"]
-    bench["workloads"] += waiting["workloads"]
-    bench["end_to_end"] += waiting["end_to_end"]
-    bench["per_layer"] += waiting["per_layer"]
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        bench[key] += lacking(key)
     for m in bench["end_to_end"]:
         if m["name"] == "tree_s":
-            m["workloads"] += waiting["tree_s_workloads"]
+            m["workloads"] += [w for w in waiting["tree_s_workloads"] if w in new]
     for m in bench["per_layer"]:
-        m["workloads"] += waiting["per_layer_workloads"].get(m["name"], [])
+        m["workloads"] += [w for w in waiting["per_layer_workloads"].get(m["name"], [])
+                           if w in new]
     return bench
 
 

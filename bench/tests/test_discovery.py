@@ -1,6 +1,7 @@
 """A cell, a configuration, a traffic mix and a per-layer metric added as
 new files and entries only are found by the harness and run."""
 
+import copy
 import json
 import time
 from pathlib import Path
@@ -72,3 +73,23 @@ def test_unknown_workload():
     root = Path(__file__).resolve().parents[2]
     with pytest.raises(KeyError):
         harness.load_cell(root, "no-such-cell")
+
+
+def test_waiting_cells_merge_once():
+    """The tests' merge of the waiting cells adds only what
+    ``BENCHMARK.json`` lacks: no cell, configuration, metric or list entry
+    twice, and merging again changes nothing."""
+    from conftest import with_waiting_cells
+
+    root = Path(__file__).resolve().parents[2]
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    merged = with_waiting_cells(copy.deepcopy(bench))
+    cells = [w["name"] for w in merged["workloads"]]
+    assert {w["name"] for w in bench["workloads"]} < set(cells)
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [e["name"] for e in merged[key]]
+        assert len(names) == len(set(names)), key
+    for m in merged["end_to_end"] + merged["per_layer"]:
+        listed = m.get("workloads", [])
+        assert len(listed) == len(set(listed)) and set(listed) <= set(cells), m["name"]
+    assert with_waiting_cells(copy.deepcopy(merged)) == merged

@@ -51,7 +51,9 @@ def altered_answer(monkeypatch):
 
 
 def half_batch(monkeypatch):
-    """The second half of every launch's lanes solves the first half's seeds."""
+    """The first half of every launch's lanes solves the second half's seeds.
+    The server pads a launch with copies of its first lane, so a launch of
+    two requests or more answers some wrongly, however few it holds."""
     from repro.solver.backends import BatchBackend
 
     orig = BatchBackend.solve_raw
@@ -59,7 +61,7 @@ def half_batch(monkeypatch):
     def half(self, cfg, g, seeds, num_seeds, ell=None):
         seeds = jnp.asarray(seeds)
         h = seeds.shape[0] // 2
-        return orig(self, cfg, g, seeds.at[h:2 * h].set(seeds[:h]), num_seeds, ell=ell)
+        return orig(self, cfg, g, seeds.at[:h].set(seeds[h:2 * h]), num_seeds, ell=ell)
 
     monkeypatch.setattr(BatchBackend, "solve_raw", half)
 
@@ -86,19 +88,39 @@ def test_fault_is_not_correct(tiny_root, fresh_jit, monkeypatch, cell, fault):
     assert not res["correct"], res["checks"]
 
 
-def test_mesh_exchange_left_out(tmp_path):
+MESH_FAULTS = ("exchange_left_out", "unchanged_state", "altered_answer")
+
+
+@pytest.fixture(scope="module")
+def mesh_runs(tmp_path_factory):
+    """The mesh cell's runs on four virtual devices, in one process: sound,
+    then with each of ``MESH_FAULTS`` planted; the result lines by fault."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, str(HERE / "mesh_fault.py"),
+                          str(tmp_path_factory.mktemp("mesh")), *MESH_FAULTS],
+                         env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    runs = [json.loads(line) for line in out.stdout.splitlines()
+            if line.startswith('{"correct"')]
+    return {r["fault"]: r for r in runs}
+
+
+def test_mesh_exchange_left_out(mesh_runs):
     """On four virtual devices: the sound mesh run is correct, and with the
     all-gather of the per-round state replaced by the chip's own block it
     is not."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    out = subprocess.run([sys.executable, str(HERE / "mesh_fault.py"), str(tmp_path)],
-                         env=env, capture_output=True, text=True, timeout=600)
-    assert out.returncode == 0, out.stderr[-3000:]
-    sound, broken = (json.loads(line) for line in out.stdout.splitlines()
-                     if line.startswith('{"correct"'))
+    sound, broken = mesh_runs["sound"], mesh_runs["exchange_left_out"]
     assert sound["correct"] and sound["device"]["count"] == 4, sound["checks"]
+    assert all(c["value"] == 0 for c in sound["checks"].values())
     assert not broken["correct"], broken["checks"]
+
+
+@pytest.mark.parametrize("fault", ["unchanged_state", "altered_answer"])
+def test_mesh_fault_is_not_correct(mesh_runs, fault):
+    """A mesh relaxation round that returns its state unchanged, and a
+    total altered where the engine's result is made, read not correct."""
+    assert not mesh_runs[fault]["correct"], mesh_runs[fault]["checks"]
 
 
 @pytest.mark.parametrize("cell", ["solve-s17-k16", "serve-s14-fresh"])
